@@ -43,7 +43,7 @@ import numpy as np
 from benchmarks.common import RESULTS_DIR, emit
 from repro.config import resolve_config
 from repro.core.serving_scheduler import ServingScheduler
-from repro.launch.serve import _build_runtime, _make_batches
+from repro.launch.serve import build_runtime, _make_batches
 from repro.serving.control_plane import ControlPlane
 from repro.serving.metrics import MetricsRegistry
 
@@ -146,7 +146,7 @@ def run(rounds: int, requests: int) -> dict:
                            "priorities": priorities}}
 
     with tempfile.TemporaryDirectory() as d:
-        names, rt, refs = _build_runtime(cfg, d)
+        names, rt, refs = build_runtime(cfg, d)
         for name, batch in _make_batches(cfg, refs).items():
             rt.forward(name, batch)             # warm: jit compile per block
         sched = ServingScheduler.from_config(rt, cfg)

@@ -19,6 +19,7 @@ co-resident models under ONE budget while hot units stay cached.
 """
 from __future__ import annotations
 
+import functools
 import time
 from collections import deque
 from dataclasses import dataclass
@@ -37,7 +38,23 @@ from repro.kernels.qtensor import (QuantizedTensor, cast_unit_params,
 from repro.kernels.swap_linear import vmem_bytes
 from repro.models.layers import linear, rms_norm, softcap
 from repro.store import build_store
-from repro.models.transformer import Model, apply_layer
+from repro.models.transformer import (Model, apply_layer_jit,
+                                     apply_layer_paged)
+
+
+@functools.partial(jax.jit, static_argnums=(0,))
+def head_logits(cfg, final_norm, w, h):
+    """Final norm + lm_head projection to fp32 logits, one compiled program
+    (as ``apply_layer_jit`` is for a layer). A quantized head streams
+    through the fused kernel (vocab projections are the odd-shaped case
+    the padded swap_linear grid covers)."""
+    h = rms_norm(h, final_norm.astype(h.dtype), cfg.norm_eps,
+                 plus_one=cfg.post_norms)
+    if isinstance(w, QuantizedTensor):
+        logits = linear(h.astype(jnp.float32), w)
+    else:
+        logits = h.astype(jnp.float32) @ w.astype(jnp.float32)
+    return softcap(logits, cfg.final_logit_softcap)
 
 
 def swap_schedule(eng: SwapEngine, blocks, unit_names: Sequence[str], m: int):
@@ -410,21 +427,13 @@ class SwappedModel:
 
     # ------------------------------------------------------------ apply fns
     def _head_logits(self, uparams: dict, h):
-        """Final-norm + lm_head projection; a quantized head streams through
-        the fused kernel (vocab projections are the odd-shaped case the
-        padded swap_linear grid now covers)."""
-        cfg = self.cfg
-        h = rms_norm(h, jnp.asarray(uparams["final_norm"]).astype(h.dtype),
-                     cfg.norm_eps, plus_one=cfg.post_norms)
-        w = uparams.get("lm_head")
-        if w is None:
+        """Final-norm + lm_head projection as one compiled program, like
+        the layers (:func:`head_logits`)."""
+        if uparams.get("lm_head") is None:
             raise ValueError("tied head needs the embed unit resident; "
                              "SwappedModel stores lm_head explicitly")
-        if isinstance(w, QuantizedTensor):
-            logits = linear(h.astype(jnp.float32), w)
-        else:
-            logits = h.astype(jnp.float32) @ jnp.asarray(w, jnp.float32)
-        return softcap(logits, cfg.final_logit_softcap)
+        return head_logits(self.cfg, uparams["final_norm"],
+                           uparams["lm_head"], h)
 
     def _apply_unit(self, unit: Unit, uparams: dict, x, positions, batch,
                     collect: Optional[dict] = None):
@@ -435,12 +444,14 @@ class SwappedModel:
                 materialize_tree(uparams), batch, "prefill")
             return x, positions
         if unit.kind == "head":
-            return self._head_logits(uparams, x), positions
+            # only the last position's logits leave a prefill (as in
+            # Model.prefill): the head never materializes [B, S, vocab]
+            return self._head_logits(uparams, x[:, -1:]), positions
         kind = "dense" if unit.kind == "shared_attn" else unit.kind
         is_local = cfg.is_local_layer(unit.layer_id)
         p = cast_unit_params(uparams, jnp.dtype(cfg.dtype))
-        x, new_cache, _ = apply_layer(cfg, kind, p, x, positions, is_local,
-                                      None, None, "prefill")
+        x, new_cache, _ = apply_layer_jit(cfg, kind, p, x, positions,
+                                          is_local, None, None, "prefill")
         if collect is not None and unit.layer_id is not None:
             # prefill cache (e.g. the prompt's K/V) captured per layer so a
             # serving admit can seed the paged pool without a second pass
@@ -515,7 +526,7 @@ class SwappedModel:
                             else:
                                 kind = "dense" if unit.kind == "shared_attn" else unit.kind
                                 pc = cast_unit_params(p, jnp.dtype(cfg.dtype))
-                                x, caches[ui], _ = apply_layer(
+                                x, caches[ui], _ = apply_layer_jit(
                                     cfg, kind, pc, x, positions,
                                     cfg.is_local_layer(unit.layer_id),
                                     caches[ui], pos, "decode")
@@ -573,11 +584,10 @@ class SwappedModel:
                         kind = ("dense" if unit.kind == "shared_attn"
                                 else unit.kind)
                         pc = cast_unit_params(p, jnp.dtype(cfg.dtype))
-                        x, _, _ = apply_layer(
+                        x = apply_layer_paged(
                             cfg, kind, pc, x, positions,
                             cfg.is_local_layer(unit.layer_id),
-                            None, batch["pos"], "decode",
-                            paged=view.bind(unit.layer_id))
+                            view.bind(unit.layer_id))
                 x = jax.block_until_ready(x)
                 eng.record_exec(time.perf_counter() - t0)
         finally:
@@ -635,11 +645,7 @@ class SwappedModel:
         state.t_active += time.perf_counter() - t_start
         if not state.done:
             return state, None
-        x = state.x
-        if x.ndim == 3 and x.shape[-1] == self.cfg.vocab_size:
-            state.logits = x[:, -1:]
-        else:
-            state.logits = x
+        state.logits = state.x
         st = eng.stats
         return state, {
             "latency_s": state.t_active,

@@ -25,8 +25,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import keystr, tree_flatten_with_path
-
 POD_AXIS = "pod"
 DATA_AXIS = "data"
 MODEL_AXIS = "model"
@@ -114,11 +112,11 @@ def _path_key(key: jax.Array, path: str) -> jax.Array:
 
 def init_from_defs(defs, key: jax.Array):
     """defs: pytree (nested dicts) of ParamDef -> pytree of arrays."""
-    flat, treedef = tree_flatten_with_path(
+    flat, treedef = jax.tree.flatten_with_path(
         defs, is_leaf=lambda x: isinstance(x, ParamDef))
     arrs = []
     for path, d in flat:
-        pstr = keystr(path)
+        pstr = jax.tree_util.keystr(path)
         dt = jnp.dtype(d.dtype)
         if d.init == "zeros":
             arrs.append(jnp.zeros(d.shape, dt))

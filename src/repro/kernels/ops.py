@@ -1,9 +1,10 @@
 """jit'd public wrappers for the Pallas kernels.
 
-On TPU the real kernels run; on CPU (this container, and any host-only test
-run) the wrappers run the kernels in interpret mode for small shapes or fall
-back to the jnp oracle — dry-run lowering for the host platform never embeds
-a Mosaic custom-call.
+With ``interpret=None`` each wrapper picks its path from the platform JAX
+runs on: the Mosaic kernel on a TPU, the ``kernels/ref.py`` oracle on any
+other backend. The choice reads ``jax.default_backend()`` and nothing else,
+so a TPU run always lowers to a ``tpu_custom_call``. ``interpret=True``
+runs the kernel body through the Pallas interpreter (the CPU tests).
 """
 from __future__ import annotations
 
@@ -22,10 +23,7 @@ from repro.kernels.swap_linear_q import swap_linear_q as _swap_linear_q
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except RuntimeError:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("act", "interpret"))

@@ -2,8 +2,13 @@
 
 The paged companion to kernels/flash_attention: instead of a contiguous
 [B, S, KV, hd] cache, K/V live in a shared pool of fixed-size token pages
-([P, T, KV, hd], see serving/paged_kv.py) and each sequence owns an ordered
-page list. The kernel gathers pages through the SCALAR-PREFETCHED page table
+([KV, P, T, hd], see serving/paged_kv.py) and each sequence owns an ordered
+page list. The KV-head axis leads so that one (kv, page) block is a
+(page_tokens, hd) tile: the TPU lowering wants a block's last two dims
+divisible by (8, 128) or equal to the array's, and a KV-minor pool would
+hand it a second-minor block dim of 1.
+
+The kernel gathers pages through the SCALAR-PREFETCHED page table
 (``pltpu.PrefetchScalarGridSpec``): the index map of the K/V operands reads
 ``page_table[b, j]`` to pick which physical page the next grid step streams
 into VMEM, so the gather costs nothing over the contiguous layout — the DMA
@@ -55,7 +60,7 @@ def _kernel(pt_ref, sl_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
     @pl.when(needed)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)          # [G, hd]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)    # [T, hd]
+        k = k_ref[0, 0].astype(jnp.float32)          # [T, hd]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
         if softcap is not None:
             s = softcap * jnp.tanh(s / softcap)
@@ -72,7 +77,7 @@ def _kernel(pt_ref, sl_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
         l_ref[...] = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
         m_ref[...] = m_new
         acc_ref[...] = acc_ref[...] * corr + jnp.dot(
-            p, v_ref[0, :, 0, :].astype(jnp.float32),
+            p, v_ref[0, 0].astype(jnp.float32),
             preferred_element_type=jnp.float32)
 
     @pl.when(j == n_pages - 1)
@@ -88,12 +93,12 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                     softcap: Optional[float] = None,
                     interpret: bool = False) -> jax.Array:
     """q: [B, H, hd] (one decode token per sequence); k/v_pages:
-    [P, T, KV, hd] shared page pools; page_table: [B, NP] int32 physical page
+    [KV, P, T, hd] shared page pools; page_table: [B, NP] int32 physical page
     ids (pad with 0 past a sequence's pages); seq_lens: [B] int32 tokens
     valid per sequence (the query token included). Returns [B, H, hd]."""
     B, H, hd = q.shape
-    P, T, KV, hd_k = k_pages.shape
-    assert v_pages.shape == (P, T, KV, hd_k) and hd == hd_k, \
+    KV, P, T, hd_k = k_pages.shape
+    assert v_pages.shape == (KV, P, T, hd_k) and hd == hd_k, \
         (q.shape, k_pages.shape, v_pages.shape)
     assert H % KV == 0, (H, KV)
     G = H // KV
@@ -110,10 +115,10 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
             in_specs=[
                 pl.BlockSpec((1, 1, G, hd),
                              lambda b, kv, j, pt, sl: (b, kv, 0, 0)),
-                pl.BlockSpec((1, T, 1, hd),
-                             lambda b, kv, j, pt, sl: (pt[b, j], 0, kv, 0)),
-                pl.BlockSpec((1, T, 1, hd),
-                             lambda b, kv, j, pt, sl: (pt[b, j], 0, kv, 0)),
+                pl.BlockSpec((1, 1, T, hd),
+                             lambda b, kv, j, pt, sl: (kv, pt[b, j], 0, 0)),
+                pl.BlockSpec((1, 1, T, hd),
+                             lambda b, kv, j, pt, sl: (kv, pt[b, j], 0, 0)),
             ],
             out_specs=pl.BlockSpec((1, 1, G, hd),
                                    lambda b, kv, j, pt, sl: (b, kv, 0, 0)),

@@ -129,8 +129,7 @@ def cast_unit_params(uparams, dtype):
     mixes, norms) follows the seed's cast — dequantized on device, floats
     cast to ``dtype``.
     """
-    from repro.compat import tree_flatten_with_path, tree_unflatten
-    flat, treedef = tree_flatten_with_path(uparams, is_leaf=is_quantized)
+    flat, treedef = jax.tree.flatten_with_path(uparams, is_leaf=is_quantized)
     leaves = []
     for path, leaf in flat:
         if isinstance(leaf, QuantizedTensor):
@@ -143,4 +142,4 @@ def cast_unit_params(uparams, dtype):
         if jnp.issubdtype(a.dtype, jnp.floating):
             a = a.astype(dtype)
         leaves.append(a)
-    return tree_unflatten(treedef, leaves)
+    return jax.tree.unflatten(treedef, leaves)

@@ -87,14 +87,19 @@ def paged_attention_ref(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                         softcap: Optional[float] = None) -> jax.Array:
     """Gather-then-attend oracle for kernels/paged_attention: materialize
     each sequence's pages contiguously ([B, NP*T, KV, hd]) and run masked
-    single-query attention. q: [B, H, hd]; returns [B, H, hd]."""
+    single-query attention. q: [B, H, hd]; k/v_pages: [KV, P, T, hd];
+    returns [B, H, hd]."""
     B, H, hd = q.shape
-    P, T, KV, _ = k_pages.shape
+    KV, P, T, _ = k_pages.shape
     G = H // KV
     NP = page_table.shape[1]
     scale = hd ** -0.5 if scale is None else scale
-    k = k_pages[page_table].reshape(B, NP * T, KV, hd)
-    v = v_pages[page_table].reshape(B, NP * T, KV, hd)
+
+    def gather(pages):          # [KV, B, NP, T, hd] -> [B, NP*T, KV, hd]
+        return pages[:, page_table].transpose(1, 2, 3, 0, 4).reshape(
+            B, NP * T, KV, hd)
+
+    k, v = gather(k_pages), gather(v_pages)
     qf = q.reshape(B, KV, G, hd).astype(jnp.float32)
     s = jnp.einsum("bkgh,bskh->bkgs", qf, k.astype(jnp.float32)) * scale
     if softcap is not None:

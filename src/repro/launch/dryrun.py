@@ -1,8 +1,10 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                            + " --xla_force_host_platform_device_count=512").strip()
-# The two lines above MUST run before any jax import (device count locks at
-# first init). 512 placeholder host devices back the production meshes.
+# The lines above MUST run before any jax import (platform and device count
+# lock at first init). The dry-run only lowers for 512 placeholder host
+# devices, so it never opens an accelerator, even on a host that has one.
 
 import argparse          # noqa: E402
 import json              # noqa: E402

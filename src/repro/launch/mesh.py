@@ -11,7 +11,6 @@ def make_production_mesh(*, multi_pod: bool = False):
     axis is pure data parallelism across pods."""
     import jax
 
-    from repro.compat import make_mesh
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     n = int(np.prod(shape))
@@ -21,10 +20,15 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"need {n} devices for mesh {shape}, have {len(devs)} — the "
             f"dry-run must set --xla_force_host_platform_device_count=512 "
             f"before any jax import")
-    return make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_smoke_mesh():
     """1-device mesh with the production axis names (CI smoke tests)."""
-    from repro.compat import make_mesh
-    return make_mesh((1, 1), ("data", "model"))
+    return _auto_mesh((1, 1), ("data", "model"))
+
+
+def _auto_mesh(shape, axes):
+    import jax
+    from jax.sharding import AxisType
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))

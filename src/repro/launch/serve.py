@@ -52,6 +52,7 @@ from repro.core.cost_model import DelayModel
 from repro.core.multi_model import MultiModelRuntime
 from repro.core.runtime import SwappedModel
 from repro.core.serving_scheduler import ServingScheduler
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.train import scale_config
 from repro.models.transformer import Model
 from repro.serving.batch_engine import BatchDecodeEngine
@@ -67,7 +68,7 @@ def _percentile(xs, q: float) -> float:
 
 
 # ----------------------------------------------------------------- assembly
-def _build_runtime(cfg: ServeConfig, workdir: str):
+def build_runtime(cfg: ServeConfig, workdir: str):
     """Resolved config -> planned MultiModelRuntime + (model, params) refs.
     The ONE construction path every mode shares: the runtime knobs come off
     ``cfg.runtime``, the tenant set off ``cfg.model_names()``."""
@@ -78,7 +79,7 @@ def _build_runtime(cfg: ServeConfig, workdir: str):
     for i, arch in enumerate(names):
         mcfg = scale_config(get_arch(arch), cfg.reduce)
         model = Model(mcfg)
-        params = model.init(jax.random.key(i))
+        params = model.init_serving(jax.random.key(i))
         rt.add_model(arch, model, params, workdir)
         refs[arch] = (model, params)
     rt.plan(batch=cfg.workload.requests, seq=cfg.workload.prompt_len)
@@ -102,7 +103,7 @@ def _build_multi_runtime(cfg: ServeConfig, workdir: str):
     """Legacy --multi setup (>= 2 tenants enforced, as before)."""
     if len(cfg.model_names()) < 2:
         raise SystemExit("--multi wants at least two comma-separated archs")
-    return _build_runtime(cfg, workdir)
+    return build_runtime(cfg, workdir)
 
 
 # ------------------------------------------------------------ profile mode
@@ -116,7 +117,7 @@ def serve_profile(cfg: ServeConfig) -> None:
     rng = np.random.default_rng(0)
 
     with tempfile.TemporaryDirectory() as d:
-        names, rt, refs = _build_runtime(cfg, d)
+        names, rt, refs = build_runtime(cfg, d)
         batches = _make_batches(cfg, refs)
         for arch in names:
             rt.forward(arch, batches[arch])     # warm: jit compile per block
@@ -171,7 +172,7 @@ def serve_http(cfg: ServeConfig) -> None:
     (or Ctrl-C). Everything observable in-process is scrapeable at
     ``/metrics``; requests submit/poll/cancel over plain JSON."""
     with tempfile.TemporaryDirectory() as d:
-        names, rt, refs = _build_runtime(cfg, d)
+        names, rt, refs = build_runtime(cfg, d)
         batches = _make_batches(cfg, refs)
         for arch in names:
             rt.forward(arch, batches[arch])     # warm: jit compile per block
@@ -409,7 +410,7 @@ def serve_single(cfg: ServeConfig) -> None:
     if not mcfg.supports_decode():
         raise SystemExit(f"{mcfg.name} is encoder-only: no decode serving")
     model = Model(mcfg)
-    params = model.init(jax.random.key(0))
+    params = model.init_serving(jax.random.key(0))
     rng = np.random.default_rng(0)
 
     if cfg.runtime.paged:
@@ -652,6 +653,7 @@ def main(argv=None) -> None:
                           "mode": dispatch_mode(cfg),
                           "layers": dict(layers)}, indent=2, sort_keys=True))
         return
+    enable_compile_cache()
     run_config(cfg)
 
 

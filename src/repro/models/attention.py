@@ -44,13 +44,6 @@ def _flash_decode_sharded(q, cache_k, cache_v, k_new, v_new, decode_pos,
       3. combine with pmax/psum (flash-decoding) — bytes moved per layer are
          O(B*H*hd), not O(B*S*KV*hd).
     """
-    try:
-        from jax.shard_map import shard_map
-    except ImportError:  # jax 0.8: still under experimental
-        import warnings
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore", DeprecationWarning)
-            from jax.experimental.shard_map import shard_map
     from repro.distributed.sharding import get_mesh
     mesh = get_mesh()
     B, _, H, hd = q.shape
@@ -108,11 +101,11 @@ def _flash_decode_sharded(q, cache_k, cache_v, k_new, v_new, decode_pos,
     cache_spec = P(bax if bax else None, axes, None, None)
     rep = P(bax if bax else None, None, None, None)
     pos_spec = P(bax if bax else None)
-    out, ck, cv = shard_map(
+    out, ck, cv = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(rep, cache_spec, cache_spec, rep, rep, pos_spec),
         out_specs=(rep, cache_spec, cache_spec),
-        check_rep=False,
+        check_vma=False,
     )(q, cache_k, cache_v, k_new, v_new, decode_pos)
     return out, ck, cv
 
@@ -201,10 +194,25 @@ def gqa_defs(cfg: ModelConfig) -> dict:
     return d
 
 
-def _attn_scale(cfg: ModelConfig) -> float:
+def attn_scale(cfg: ModelConfig) -> float:
     if cfg.query_pre_attn_scalar is not None:
         return cfg.query_pre_attn_scalar ** -0.5
     return cfg.resolved_head_dim ** -0.5
+
+
+def gqa_qkv(cfg: ModelConfig, p: dict, x: jax.Array, positions: jax.Array
+            ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Projections + rotary: x [B,S,D] -> q [B,S,H,hd], k/v [B,S,KV,hd]."""
+    B, S, D = x.shape
+    H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
+    q = linear(x, p["wq"], p.get("bq")).reshape(B, S, H, hd)
+    k = linear(x, p["wk"], p.get("bk")).reshape(B, S, KV, hd)
+    v = linear(x, p["wv"], p.get("bv")).reshape(B, S, KV, hd)
+    if cfg.rope_type != "none":
+        sections = cfg.mrope_sections if cfg.rope_type == "mrope" else None
+        ang = rope_angles(positions, hd, cfg.rope_theta, sections)
+        q, k = apply_rope(q, ang), apply_rope(k, ang)
+    return q, k, v
 
 
 def gqa_apply(cfg: ModelConfig, p: dict, x: jax.Array, positions: jax.Array,
@@ -214,14 +222,7 @@ def gqa_apply(cfg: ModelConfig, p: dict, x: jax.Array, positions: jax.Array,
     Decode: cache={'k','v'} of [B,Smax,KV,hd], decode_pos [B] write index."""
     B, S, D = x.shape
     H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
-    q = linear(x, p["wq"], p.get("bq")).reshape(B, S, H, hd)
-    k = linear(x, p["wk"], p.get("bk")).reshape(B, S, KV, hd)
-    v = linear(x, p["wv"], p.get("bv")).reshape(B, S, KV, hd)
-
-    if cfg.rope_type != "none":
-        sections = cfg.mrope_sections if cfg.rope_type == "mrope" else None
-        ang = rope_angles(positions, hd, cfg.rope_theta, sections)
-        q, k = apply_rope(q, ang), apply_rope(k, ang)
+    q, k, v = gqa_qkv(cfg, p, x, positions)
 
     context_parallel = False
     if decode_pos is None:
@@ -256,7 +257,7 @@ def gqa_apply(cfg: ModelConfig, p: dict, x: jax.Array, positions: jax.Array,
             and cache["k"].shape[1] <= cfg.sliding_window):
         # ring-buffer (windowed) cache: slot = pos % W (§Perf, beyond-paper)
         out, cache = _windowed_decode(q, cache, k, v, decode_pos,
-                                      scale=_attn_scale(cfg),
+                                      scale=attn_scale(cfg),
                                       logit_cap=cfg.attn_logit_softcap)
         out = linear(out.reshape(B, S, H * hd).astype(x.dtype), p["wo"])
         return out, cache
@@ -272,7 +273,7 @@ def gqa_apply(cfg: ModelConfig, p: dict, x: jax.Array, positions: jax.Array,
                 out, ck, cv = _flash_decode_sharded(
                     q, cache["k"], cache["v"], k, v, decode_pos,
                     axis=SHARDED_DECODE_AXIS, batch_axes=("pod", "data"),
-                    scale=_attn_scale(cfg), window=w,
+                    scale=attn_scale(cfg), window=w,
                     logit_cap=cfg.attn_logit_softcap, block_local=bl)
                 out = linear(out.reshape(B, S, H * hd).astype(x.dtype), p["wo"])
                 return out, {"k": ck, "v": cv}
@@ -287,7 +288,7 @@ def gqa_apply(cfg: ModelConfig, p: dict, x: jax.Array, positions: jax.Array,
         k_all, v_all, valid = k, v, None
 
     out = online_attention(q, k_all, v_all, q_pos, valid, causal=not cfg.is_encoder,
-                           window=window, scale=_attn_scale(cfg),
+                           window=window, scale=attn_scale(cfg),
                            logit_cap=cfg.attn_logit_softcap, chunk=chunk,
                            block_local=block_local)
     out = linear(out.reshape(B, S, H * hd).astype(x.dtype), p["wo"])
@@ -300,35 +301,13 @@ def gqa_apply(cfg: ModelConfig, p: dict, x: jax.Array, positions: jax.Array,
     return out, new_cache
 
 
-def gqa_apply_paged(cfg: ModelConfig, p: dict, x: jax.Array,
-                    positions: jax.Array, is_local, paged) -> jax.Array:
-    """Single-token batched decode through the paged KV cache
-    (serving/paged_kv.py): q/k/v projections + rope exactly as
-    :func:`gqa_apply`, then the new K/V are appended to each sequence's
-    pages and attention gathers through the page table
-    (kernels/paged_attention via the ops auto-dispatch).
-
-    ``paged`` is a layer-bound attend hook (``PagedBatchView.bind``); the
-    engine path applies units eagerly, so ``is_local`` is a concrete bool
-    and the window resolves to a STATIC int the kernel can specialize on.
-    """
-    B, S, D = x.shape
-    assert S == 1, "paged attention is the single-token decode path"
-    H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
-    q = linear(x, p["wq"], p.get("bq")).reshape(B, S, H, hd)
-    k = linear(x, p["wk"], p.get("bk")).reshape(B, S, KV, hd)
-    v = linear(x, p["wv"], p.get("bv")).reshape(B, S, KV, hd)
-    if cfg.rope_type != "none":
-        sections = cfg.mrope_sections if cfg.rope_type == "mrope" else None
-        ang = rope_angles(positions, hd, cfg.rope_theta, sections)
-        q, k = apply_rope(q, ang), apply_rope(k, ang)
-    window = None
+def paged_window(cfg: ModelConfig, is_local: bool) -> Optional[int]:
+    """The static attention window of one layer on the paged decode path
+    (the kernel specializes on it); None = global attention."""
     if cfg.sliding_window is not None and (cfg.layer_pattern == "swa"
-                                           or bool(is_local)):
-        window = int(cfg.sliding_window)
-    out = paged.attend(q[:, 0], k[:, 0], v[:, 0], scale=_attn_scale(cfg),
-                       window=window, softcap=cfg.attn_logit_softcap)
-    return linear(out.reshape(B, S, H * hd).astype(x.dtype), p["wo"])
+                                           or is_local):
+        return int(cfg.sliding_window)
+    return None
 
 
 def _windowed_decode(q, cache, k_new, v_new, pos, *, scale, logit_cap):

@@ -16,6 +16,7 @@ online-softmax attention); "decode" runs one token against a cache.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -31,7 +32,7 @@ from repro.distributed.sharding import (
 from repro.models import attention as attn_mod
 from repro.models import moe as moe_mod
 from repro.models import ssm as ssm_mod
-from repro.models.layers import mlp_apply, mlp_defs, rms_norm, softcap
+from repro.models.layers import linear, mlp_apply, mlp_defs, rms_norm, softcap
 
 LOSS_CHUNK = 512        # token chunk for the logsumexp loss (never [T, V] at once)
 
@@ -135,11 +136,8 @@ def model_defs(cfg: ModelConfig) -> Tuple[dict, List[Segment]]:
 # ------------------------------------------------------------------ layer
 def apply_layer(cfg: ModelConfig, kind: str, p: dict, x: jax.Array,
                 positions: jax.Array, is_local, cache, decode_pos,
-                mode: str, paged=None):
-    """Returns (x, new_cache, aux). ``paged`` (decode only) is a layer-bound
-    paged-attention hook (serving/paged_kv.PagedBatchView.bind): attention
-    K/V land in the page pool instead of a contiguous cache, and
-    ``new_cache`` is None."""
+                mode: str):
+    """Returns (x, new_cache, aux)."""
     aux = jnp.zeros((), jnp.float32)
     if kind == "mamba2":
         h0 = cache["h"] if cache is not None else None
@@ -169,13 +167,18 @@ def apply_layer(cfg: ModelConfig, kind: str, p: dict, x: jax.Array,
     if cfg.mla is not None:
         a_out, new_cache = attn_mod.mla_apply(cfg, p["attn"], h, positions,
                                               cache, decode_pos)
-    elif paged is not None and mode == "decode":
-        a_out = attn_mod.gqa_apply_paged(cfg, p["attn"], h, positions,
-                                         is_local, paged)
-        new_cache = None
     else:
         a_out, new_cache = attn_mod.gqa_apply(cfg, p["attn"], h, positions,
                                               is_local, cache, decode_pos)
+    x, aux = _block_ffn(cfg, kind, p, x, a_out)
+    return x, new_cache, aux
+
+
+def _block_ffn(cfg: ModelConfig, kind: str, p: dict, x: jax.Array,
+               a_out: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """A transformer block after its attention: residual, then the dense or
+    routed FFN with its own residual. Returns (x, aux)."""
+    aux = jnp.zeros((), jnp.float32)
     if cfg.post_norms:
         a_out = rms_norm(a_out, p["post_ln1"], cfg.norm_eps, plus_one=True)
     x = x + a_out
@@ -186,7 +189,51 @@ def apply_layer(cfg: ModelConfig, kind: str, p: dict, x: jax.Array,
         f_out = mlp_apply(cfg, p["ffn"], h)
     if cfg.post_norms:
         f_out = rms_norm(f_out, p["post_ln2"], cfg.norm_eps, plus_one=True)
-    return x + f_out, new_cache, aux
+    return x + f_out, aux
+
+
+# One layer as one compiled program, with its parameters as arguments: the
+# swapped executors run units through this, so a swapped-in unit reuses the
+# executable of every earlier unit of its kind and shape and each layer is
+# one dispatch, not one per op. On the CPU it also reproduces the
+# whole-model program's rounding: applied op by op there, every bf16
+# intermediate is rounded on its own, and across 36 layers that drift
+# passes the 2e-2 tolerance against ``Model.prefill``. On a TPU v5e the
+# logits come out the same either way. Static: cfg, kind, is_local, mode.
+apply_layer_jit = jax.jit(apply_layer, static_argnums=(0, 1, 5, 8))
+
+
+@functools.partial(jax.jit, static_argnums=(0,))
+def _paged_qkv(cfg: ModelConfig, p: dict, x: jax.Array, positions):
+    h = rms_norm(x, p["ln1"], cfg.norm_eps, plus_one=cfg.post_norms)
+    return attn_mod.gqa_qkv(cfg, p["attn"], h, positions)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _paged_out(cfg: ModelConfig, kind: str, p: dict, x: jax.Array,
+               attn: jax.Array) -> jax.Array:
+    B, S, _ = x.shape
+    a_out = linear(attn.reshape(B, S, -1).astype(x.dtype), p["attn"]["wo"])
+    return _block_ffn(cfg, kind, p, x, a_out)[0]
+
+
+def apply_layer_paged(cfg: ModelConfig, kind: str, p: dict, x: jax.Array,
+                      positions: jax.Array, is_local: bool, paged
+                      ) -> jax.Array:
+    """One decode token per sequence ([B, 1, D]) through the paged KV cache.
+
+    ``paged`` is a layer-bound attend hook (``PagedBatchView.bind``): it
+    appends the new K/V to each sequence's pages on the host, then runs
+    paged attention through the page table. The projections before it and
+    the FFN after it are compiled programs, like :data:`apply_layer_jit`.
+    Returns the layer's output x."""
+    assert x.shape[1] == 1, "paged attention is the single-token decode path"
+    q, k, v = _paged_qkv(cfg, p, x, positions)
+    out = paged.attend(q[:, 0], k[:, 0], v[:, 0],
+                       scale=attn_mod.attn_scale(cfg),
+                       window=attn_mod.paged_window(cfg, is_local),
+                       softcap=cfg.attn_logit_softcap)
+    return _paged_out(cfg, kind, p, x, out[:, None])
 
 
 # ------------------------------------------------------------------ stack
@@ -249,6 +296,12 @@ class Model:
             segs.append(jax.vmap(lambda k, d=sdefs: init_from_defs(d, k))(keys))
         params["segments"] = segs
         return params
+
+    def init_serving(self, key: jax.Array) -> dict:
+        """Serving weights: :meth:`init`'s values cast to the compute dtype
+        (``cfg.dtype``) inside one jitted program, so swap units are stored
+        and streamed at serving precision."""
+        return jax.jit(lambda k: self.cast(self.init(k)))(key)
 
     def param_struct(self, dtype: Optional[str] = None) -> dict:
         """ShapeDtypeStruct pytree (no allocation) — dry-run stand-in.

@@ -85,7 +85,7 @@ def _default_build_model(arch: str, reduce: str, seed: int):
     import jax
     cfg = scale_config(get_arch(arch), reduce)
     model = Model(cfg)
-    params = model.init(jax.random.key(seed))
+    params = model.init_serving(jax.random.key(seed))
     return model, params
 
 

@@ -72,8 +72,9 @@ class PagedKVCache:
         dt = jnp.dtype(cfg.dtype)
         # page 0 is a permanently-zero SENTINEL: page tables are padded with
         # it past a sequence's pages, so the kernel's gather always lands on
-        # a real (masked) page
-        shape = (self.max_pages + 1, self.page_tokens, KV, hd)
+        # a real (masked) page. KV heads lead ([KV, P, T, hd]): the kernel
+        # streams one (page_tokens, hd) tile per (kv head, page)
+        shape = (KV, self.max_pages + 1, self.page_tokens, hd)
         self.k_pools = [np.zeros(shape, dt) for _ in range(cfg.n_layers)]
         self.v_pools = [np.zeros(shape, dt) for _ in range(cfg.n_layers)]
         self._free: List[int] = list(range(self.max_pages, 0, -1))
@@ -167,8 +168,8 @@ class PagedKVCache:
             pid = pages[pos // T]
             slot = pos % T
             n = min(T - slot, k.shape[0] - t)
-            kp[pid, slot:slot + n] = k[t:t + n]
-            vp[pid, slot:slot + n] = v[t:t + n]
+            kp[:, pid, slot:slot + n] = k[t:t + n].swapaxes(0, 1)
+            vp[:, pid, slot:slot + n] = v[t:t + n].swapaxes(0, 1)
             t += n
         self._dirty[layer] = True
 
@@ -188,8 +189,8 @@ class PagedKVCache:
         """Scatter one token per sequence ([B, KV, hd]) into pool rows
         addressed by ``last_slots`` — the vectorized decode-step write (one
         fancy-index assignment instead of B ``write`` calls per layer)."""
-        self.k_pools[layer][pids, slots] = k
-        self.v_pools[layer][pids, slots] = v
+        self.k_pools[layer][:, pids, slots] = k.swapaxes(0, 1)
+        self.v_pools[layer][:, pids, slots] = v.swapaxes(0, 1)
         self._dirty[layer] = True
 
     # ------------------------------------------------------------ views
@@ -233,8 +234,8 @@ class PagedKVCache:
 
 
 class _LayerBoundView:
-    """``PagedBatchView`` narrowed to one layer — the ``paged`` hook
-    ``models.transformer.apply_layer`` hands to ``gqa_apply_paged``."""
+    """``PagedBatchView`` narrowed to one layer — the ``paged`` hook that
+    ``models.transformer.apply_layer_paged`` attends through."""
 
     __slots__ = ("_view", "_layer")
 

@@ -199,12 +199,11 @@ class QuantizedStore(BlockStore):
 
     # ------------------------------------------------------------ build
     def _write_unit(self, name: str, params: dict) -> None:
-        from repro.compat import tree_flatten_with_path
         from repro.core.skeleton import ALIGN, skeleton_of
         from repro.kernels.dequant import quantize_int4, quantize_int8
         bits_u = self._unit_bits(name)
         quantize = quantize_int8 if bits_u == 8 else quantize_int4
-        flat, _ = tree_flatten_with_path(params)
+        flat, _ = jax.tree.flatten_with_path(params)
         # logical skeleton (nbytes/meta) WITHOUT materializing the flat fp
         # buffer — the payload below is this store's only serialization
         self.skeletons[name] = skeleton_of(params)

@@ -106,7 +106,7 @@ def test_quantized_store_moves_fewer_bytes():
 
 
 # ------------------------------------------------------------ dequant kernel
-@pytest.mark.parametrize("R,C", [(8, 128), (200, 96), (1, 7)])
+@pytest.mark.parametrize("R,C", [(8, 128), (200, 96), (1, 7), (300, 2100)])
 @pytest.mark.parametrize("out_dtype", ["float32", "bfloat16"])
 def test_dequant_kernel_matches_numpy_ref(R, C, out_dtype):
     """The Pallas kernel (interpret mode) vs a plain numpy dequant."""

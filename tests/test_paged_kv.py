@@ -29,13 +29,14 @@ def _tol(dtype):
 
 def _random_paged(rng_key, B, H, KV, hd, T, max_pages, dtype,
                   seq_lens):
-    """Random q + page pools + a SHUFFLED page table covering seq_lens."""
+    """Random q + [KV, P, T, hd] page pools + a SHUFFLED page table
+    covering seq_lens."""
     kq, kk, kv = jax.random.split(rng_key, 3)
     q = jax.random.normal(kq, (B, H, hd), dtype) * 0.5
-    k_pages = jax.random.normal(kk, (max_pages + 1, T, KV, hd), dtype) * 0.5
-    v_pages = jax.random.normal(kv, (max_pages + 1, T, KV, hd), dtype) * 0.5
-    k_pages = k_pages.at[0].set(0)        # zero sentinel
-    v_pages = v_pages.at[0].set(0)
+    k_pages = jax.random.normal(kk, (KV, max_pages + 1, T, hd), dtype) * 0.5
+    v_pages = jax.random.normal(kv, (KV, max_pages + 1, T, hd), dtype) * 0.5
+    k_pages = k_pages.at[:, 0].set(0)     # zero sentinel
+    v_pages = v_pages.at[:, 0].set(0)
     NP = max(-(-int(s) // T) for s in seq_lens)
     rng = np.random.default_rng(0)
     ids = rng.permutation(np.arange(1, max_pages + 1))
@@ -50,11 +51,15 @@ def _random_paged(rng_key, B, H, KV, hd, T, max_pages, dtype,
         np.asarray(seq_lens, np.int32))
 
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("window,softcap", [
-    (None, None), (7, None), (None, 30.0), (5, 30.0)])
-def test_paged_kernel_vs_ref(dtype, window, softcap):
-    B, H, KV, hd, T = 3, 8, 2, 64, 8
+def _gather(pool, pages):
+    """A [KV, P, T, hd] pool's pages in table order -> [n*T, KV, hd]."""
+    pool = np.asarray(pool)
+    return pool[:, pages].reshape(pool.shape[0], -1,
+                                  pool.shape[-1]).swapaxes(0, 1)
+
+
+def _check_paged_kernel(dtype, window, softcap, KV):
+    B, H, hd, T = 3, 8, 64, 8
     seq_lens = [5, 23, 16]
     q, kp, vp, pt, sl = _random_paged(jax.random.key(0), B, H, KV, hd, T,
                                       16, dtype, seq_lens)
@@ -64,6 +69,20 @@ def test_paged_kernel_vs_ref(dtype, window, softcap):
                                    softcap=softcap)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), **_tol(dtype))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("window,softcap", [
+    (None, None), (7, None), (None, 30.0), (5, 30.0)])
+def test_paged_kernel_vs_ref(dtype, window, softcap):
+    _check_paged_kernel(dtype, window, softcap, KV=2)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("window,softcap", [
+    (None, None), (7, None), (None, 30.0), (5, 30.0)])
+def test_paged_kernel_vs_ref_one_kv_head(dtype, window, softcap):
+    _check_paged_kernel(dtype, window, softcap, KV=1)
 
 
 @pytest.mark.parametrize("seq_len", [1, 8, 17, 40])
@@ -81,8 +100,8 @@ def test_paged_kernel_matches_contiguous_flash(seq_len, window):
     # KV heads to H, run causal flash over the real context, take the last
     # row (the broadcast q rows cannot influence it under causal masking)
     S = int(sl[0])
-    ctx_k = np.asarray(kp)[np.asarray(pt)[0]].reshape(-1, KV, hd)[:S]
-    ctx_v = np.asarray(vp)[np.asarray(pt)[0]].reshape(-1, KV, hd)[:S]
+    ctx_k = _gather(kp, np.asarray(pt)[0])[:S]
+    ctx_v = _gather(vp, np.asarray(pt)[0])[:S]
     for h in range(H):
         qh = jnp.broadcast_to(q[0, h][None, None, :], (1, S, hd))
         kh = jnp.asarray(ctx_k[:, h // G][None])
@@ -146,11 +165,11 @@ def test_write_page_table_roundtrip_and_sentinel():
     kv.write("a", 0, 0, k, v)               # spans a page boundary
     pt, sl = kv.page_table(["a"])
     assert sl.tolist() == [6] and pt.shape == (1, 2)
-    gathered = kv.k_pools[0][pt[0]].reshape(-1, KV, hd)[:6]
+    gathered = _gather(kv.k_pools[0], pt[0])[:6]
     np.testing.assert_array_equal(gathered, k)
     # sentinel page 0 is never handed out and never written
     assert 0 not in pt[0]
-    assert not kv.k_pools[0][0].any()
+    assert not kv.k_pools[0][:, 0].any()
     # a second, longer sequence pads the FIRST one's table row with 0s
     kv.alloc("b", 16)
     pt2, _ = kv.page_table(["a", "b"])
@@ -237,8 +256,7 @@ def test_batch_view_write_position():
     # the new row landed at position 5
     pt, sl = kv.page_table(["a"])
     assert sl.tolist() == [6]
-    np.testing.assert_array_equal(
-        kv.k_pools[0][pt[0]].reshape(-1, KV, hd)[5], kn[0])
+    np.testing.assert_array_equal(_gather(kv.k_pools[0], pt[0])[5], kn[0])
     # and the output equals the oracle over the 6-token context
     want = ref.paged_attention_ref(
         q, jnp.asarray(kv.k_pools[0]), jnp.asarray(kv.v_pools[0]),

@@ -282,7 +282,12 @@ def test_priority_inversion_regression():
         sched = ServingScheduler(rt, executors=1, preempt=True)
         lo = [sched.submit("qwen2.5-3b", setups["qwen2.5-3b"][3],
                            priority=1.0) for _ in range(3)]
-        time.sleep(0.03)                         # mid first lo pass
+        # arrive while the first lo pass is in flight: wait for the executor
+        # to take it off the queue (a warm pass lasts milliseconds, so a
+        # fixed sleep can outlast several of them)
+        t_end = time.monotonic() + 60
+        while len(sched.queue) == len(lo) and time.monotonic() < t_end:
+            time.sleep(0.0005)
         hi = sched.submit("gemma2-9b", setups["gemma2-9b"][3], priority=8.0)
         for r in lo + [hi]:
             r.wait(timeout=300)
