@@ -1,0 +1,7 @@
+"""Model step: FLOPs of every prefill and decode token processed in the
+window (bench/counts.py) over the window times the chip's bf16 peak (%)."""
+import readers
+
+
+def read(r):
+    return readers.mfu(r)
