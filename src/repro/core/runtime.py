@@ -38,6 +38,7 @@ from repro.kernels.qtensor import (QuantizedTensor, cast_unit_params,
 from repro.kernels.swap_linear import vmem_bytes
 from repro.models.layers import linear, rms_norm, softcap
 from repro.store import build_store
+from repro.tracing import span
 from repro.models.transformer import (Model, apply_layer_jit,
                                      apply_layer_paged)
 
@@ -314,14 +315,16 @@ class SwappedSequential:
         t_start = time.perf_counter()
         for bi, lo, hi, handle in swap_schedule(eng, self.plan.blocks(),
                                                 names, self.plan.m):
-            t0 = time.perf_counter()
-            ps = handle.params
-            if self.param_override is not None:
-                ps = [self.param_override(lo + off, p)
-                      for off, p in enumerate(ps)]
-            x = self._block_fn(lo, hi)(ps, x)
-            x = jax.block_until_ready(x)
-            eng.record_exec(time.perf_counter() - t0)
+            with span("exec", block=bi):
+                t0 = time.perf_counter()
+                ps = handle.params
+                if self.param_override is not None:
+                    ps = [self.param_override(lo + off, p)
+                          for off, p in enumerate(ps)]
+                x = self._block_fn(lo, hi)(ps, x)
+                with span("exec.sync"):
+                    x = jax.block_until_ready(x)
+                eng.record_exec(time.perf_counter() - t0)
         total = time.perf_counter() - t_start
         st = eng.stats
         return x, {"latency_s": total,
@@ -569,31 +572,34 @@ class SwappedModel:
         eng = self.engine
         names = [u.name for u in self.units]
         x = positions = logits = None
-        gen = swap_schedule(eng, self.plan.blocks(), names, self.plan.m)
-        try:
-            for bi, lo, hi, handle in gen:
-                t0 = time.perf_counter()
-                for ui, p in zip(range(lo, hi), handle.params):
-                    unit = self.units[ui]
-                    if unit.kind == "embed":
-                        x, positions = self.model._embed(
-                            materialize_tree(p), batch, "decode")
-                    elif unit.kind == "head":
-                        logits = self._head_logits(p, x)
-                    else:
-                        kind = ("dense" if unit.kind == "shared_attn"
-                                else unit.kind)
-                        pc = cast_unit_params(p, jnp.dtype(cfg.dtype))
-                        x = apply_layer_paged(
-                            cfg, kind, pc, x, positions,
-                            cfg.is_local_layer(unit.layer_id),
-                            view.bind(unit.layer_id))
-                x = jax.block_until_ready(x)
-                eng.record_exec(time.perf_counter() - t0)
-        finally:
-            # a raising step body must drain in-flight prefetches NOW
-            # (ledger bytes, cache leases), not at gc time
-            gen.close()
+        with span("decode_step", step=view.step):
+            gen = swap_schedule(eng, self.plan.blocks(), names, self.plan.m)
+            try:
+                for bi, lo, hi, handle in gen:
+                    with span("exec", block=bi):
+                        t0 = time.perf_counter()
+                        for ui, p in zip(range(lo, hi), handle.params):
+                            unit = self.units[ui]
+                            if unit.kind == "embed":
+                                x, positions = self.model._embed(
+                                    materialize_tree(p), batch, "decode")
+                            elif unit.kind == "head":
+                                logits = self._head_logits(p, x)
+                            else:
+                                kind = ("dense" if unit.kind == "shared_attn"
+                                        else unit.kind)
+                                pc = cast_unit_params(p, jnp.dtype(cfg.dtype))
+                                x = apply_layer_paged(
+                                    cfg, kind, pc, x, positions,
+                                    cfg.is_local_layer(unit.layer_id),
+                                    view.bind(unit.layer_id))
+                        with span("exec.sync"):
+                            x = jax.block_until_ready(x)
+                        eng.record_exec(time.perf_counter() - t0)
+            finally:
+                # a raising step body must drain in-flight prefetches NOW
+                # (ledger bytes, cache leases), not at gc time
+                gen.close()
         return logits
 
     # ------------------------------------------------------------ forward
@@ -623,25 +629,28 @@ class SwappedModel:
 
         t_start = time.perf_counter()
         pending = state.blocks[state.next_block:]
-        gen = swap_schedule(eng, pending, names, state.m)
-        try:
-            for bi, lo, hi, handle in gen:
-                t0 = time.perf_counter()
-                for u, p in zip(self.units[lo:hi], handle.params):
-                    if self.param_override is not None:
-                        p = self.param_override(u, p)
-                    state.x, state.positions = self._apply_unit(
-                        u, p, state.x, state.positions, batch,
-                        collect=state.caches)
-                state.x = jax.block_until_ready(state.x)
-                eng.record_exec(time.perf_counter() - t0)
-                state.next_block += 1
-                if (should_yield is not None and not state.done
-                        and should_yield(state)):
-                    state.preemptions += 1
-                    break
-        finally:
-            gen.close()     # drains in-flight prefetches on early exit
+        with span("prefill"):
+            gen = swap_schedule(eng, pending, names, state.m)
+            try:
+                for bi, lo, hi, handle in gen:
+                    with span("exec", block=state.next_block):
+                        t0 = time.perf_counter()
+                        for u, p in zip(self.units[lo:hi], handle.params):
+                            if self.param_override is not None:
+                                p = self.param_override(u, p)
+                            state.x, state.positions = self._apply_unit(
+                                u, p, state.x, state.positions, batch,
+                                collect=state.caches)
+                        with span("exec.sync"):
+                            state.x = jax.block_until_ready(state.x)
+                        eng.record_exec(time.perf_counter() - t0)
+                    state.next_block += 1
+                    if (should_yield is not None and not state.done
+                            and should_yield(state)):
+                        state.preemptions += 1
+                        break
+            finally:
+                gen.close()     # drains in-flight prefetches on early exit
         state.t_active += time.perf_counter() - t_start
         if not state.done:
             return state, None

@@ -2,11 +2,11 @@
 
 Storage is a pluggable tier (``repro.store``): the engine asks its
 :class:`~repro.store.BlockStore` for each unit and does the bookkeeping —
-wall-clock (t_in split into I/O + assembly, t_out, and the stall time the
-executor spends waiting on prefetch futures), actual storage->host traffic
-(``SwapStats.bytes_swapped``; quantized backends move ~4x less than the
-logical unit bytes), and a resident-bytes ledger (peak is what the paper's
-Figs. 11-13 report).
+wall-clock (t_in, t_out, and the stall time the executor spends waiting on
+prefetch futures, kept over the last ``SwapStats.HISTORY`` events), actual
+storage->host traffic (``SwapStats.bytes_swapped``; quantized backends move
+~4x less than the logical unit bytes), and a resident-bytes ledger (peak is
+what the paper's Figs. 11-13 report).
 
 The paper's ablation arms (Fig. 15) remain the engine's ``mode`` flag and are
 resolved against the store:
@@ -33,14 +33,16 @@ from __future__ import annotations
 import gc
 import threading
 import time
-from collections import OrderedDict
+from collections import OrderedDict, deque
 from concurrent.futures import Future, ThreadPoolExecutor
 from dataclasses import dataclass, field
-from typing import Callable, Dict, List, Mapping, Optional, Sequence
+from typing import (Callable, ClassVar, Deque, Dict, List, Mapping,
+                    Optional, Sequence)
 
 from repro.errors import SwapError, SwapIOError, SwapTimeoutError
 from repro.store import (BlockStore, LayerStore, MmapStore, QuantizedStore,
                          RawIOStore, as_reader)
+from repro.tracing import span
 
 __all__ = ["LayerStore", "MmapStore", "RawIOStore", "QuantizedStore",
            "MemoryLedger", "BlockCache", "size_aware_policy", "BlockHandle",
@@ -354,6 +356,10 @@ class BlockHandle:
     cached_names: List[str] = field(default_factory=list)
 
 
+def _ring() -> deque:
+    return deque(maxlen=SwapStats.HISTORY)
+
+
 @dataclass
 class SwapStats:
     """Wall-clock + byte accounting of one engine. The three byte currencies
@@ -385,14 +391,19 @@ class SwapStats:
     A healthy depth-m pipeline shows block i+1's "read" span INSIDE block
     i's "exec" span — :meth:`overlap_seconds` measures exactly that, so a
     serialization point is attributable to the stage that caused it
-    instead of disappearing into an aggregate latency."""
-    t_in: List[float] = field(default_factory=list)
-    t_in_io: List[float] = field(default_factory=list)
-    t_in_asm: List[float] = field(default_factory=list)
-    t_ex: List[float] = field(default_factory=list)
-    t_out: List[float] = field(default_factory=list)
-    t_wait: List[float] = field(default_factory=list)   # executor stalls
-    timeline: List[tuple] = field(default_factory=list)
+    instead of disappearing into an aggregate latency.
+
+    ``timeline`` and the per-event lists (``t_in``, ``t_ex``, ``t_out``,
+    ``t_wait``) are rings of the last ``HISTORY`` entries each, so a
+    long-lived server holds a bounded log (a decode step adds about 110
+    timeline events); sums over them, ``overlap_efficiency`` included,
+    cover that recent history. The byte and count fields are totals."""
+    HISTORY: ClassVar[int] = 65536
+    t_in: Deque[float] = field(default_factory=_ring)
+    t_ex: Deque[float] = field(default_factory=_ring)
+    t_out: Deque[float] = field(default_factory=_ring)
+    t_wait: Deque[float] = field(default_factory=_ring)  # stalls
+    timeline: Deque[tuple] = field(default_factory=_ring)
     peak_resident: int = 0
     bytes_swapped: int = 0       # actual storage->host I/O traffic
     bytes_logical: int = 0       # dequantized bytes those swap-ins delivered
@@ -454,9 +465,10 @@ class SwapStats:
         return total
 
     def overlap_efficiency(self) -> float:
-        """Fraction of total swap-in time hidden behind execution: 1.0 means
-        the executor never stalled on a prefetch (paper Fig. 10's ideal);
-        0.0 means every swap-in was fully visible (serial)."""
+        """Fraction of swap-in time hidden behind execution, over the last
+        ``HISTORY`` swap-ins and stalls: 1.0 means the executor never
+        stalled on a prefetch (paper Fig. 10's ideal); 0.0 means every
+        swap-in was fully visible (serial)."""
         total_in = sum(self.t_in)
         if total_in <= 0.0:
             return 1.0
@@ -653,8 +665,6 @@ class SwapEngine:
                 self.cache.release(name)
             raise
         self.stats.t_in.append(io_s + asm_s)
-        self.stats.t_in_io.append(io_s)
-        self.stats.t_in_asm.append(asm_s)
         self.stats.vmem_working_set = self.vmem_working_set
         self.stats.bytes_swapped += loaded   # actual I/O traffic: cache hits
         return handle                        # skip it, admitted loads count
@@ -667,9 +677,10 @@ class SwapEngine:
 
     def wait(self, fut: Future) -> BlockHandle:
         """Block on a prefetch future, recording the stall as visible t_in."""
-        t0 = time.perf_counter()
-        handle = fut.result()
-        t1 = time.perf_counter()
+        with span("swap.wait"):
+            t0 = time.perf_counter()
+            handle = fut.result()
+            t1 = time.perf_counter()
         self.stats.t_wait.append(t1 - t0)
         self.stats.timeline.append(("wait", t0, t1))
         return handle
@@ -678,14 +689,15 @@ class SwapEngine:
     def swap_out(self, handle: BlockHandle) -> float:
         """Write-back-free: parameters are immutable — drop references, GC.
         Cache-resident units merely drop their lease. Returns t_out."""
-        t0 = time.perf_counter()
-        handle.params = []
-        for name in handle.cached_names:
-            self.cache.release(name)
-        handle.cached_names = []
-        self.ledger.drop(id(handle))
-        gc.collect(0)
-        dt = time.perf_counter() - t0
+        with span("swap.out"):
+            t0 = time.perf_counter()
+            handle.params = []
+            for name in handle.cached_names:
+                self.cache.release(name)
+            handle.cached_names = []
+            self.ledger.drop(id(handle))
+            gc.collect(0)
+            dt = time.perf_counter() - t0
         self.stats.t_out.append(dt)
         return dt
 

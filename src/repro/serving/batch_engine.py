@@ -46,6 +46,7 @@ from repro.core.runtime import SwappedModel
 from repro.errors import SwapError
 from repro.serving.engine import Request
 from repro.serving.paged_kv import PagedBatchView, PagedKVCache
+from repro.tracing import span
 
 __all__ = ["BatchDecodeEngine", "StepTrace"]
 
@@ -94,12 +95,16 @@ class BatchDecodeEngine:
         self.failures = 0            # sequences evicted on swap failure
         self.decode_s = 0.0          # wall time inside batched decode steps
         self.prefill_s = 0.0
+        self.admit_wait_s = 0.0      # queued -> admitted, summed
+        self.admitted = 0            # admissions (re-admissions included)
         self._pending: deque = deque()
+        self._queued_at: Dict[int, float] = {}   # rid -> entered _pending
         self._active: List[_Active] = []
         self._done: set = set()
         self._known: set = set()
         self._on_retire: Dict[int, Optional[Callable]] = {}
         self._step_no = 0
+        self._kv_counters = kv.counters()    # as of the last step's end
         self._lock = threading.Lock()        # pending / done / callbacks
         self._drive = threading.Lock()       # one step() at a time
 
@@ -110,6 +115,7 @@ class BatchDecodeEngine:
             assert req.rid not in self._known, f"rid {req.rid} already known"
             self._known.add(req.rid)
             self._on_retire[req.rid] = on_retire
+            self._queued_at[req.rid] = time.perf_counter()
             self._pending.append(req)
 
     def cancel(self, rid: int) -> bool:
@@ -123,6 +129,7 @@ class BatchDecodeEngine:
                     del self._pending[i]
                     self._known.discard(rid)
                     self._on_retire.pop(rid, None)
+                    self._queued_at.pop(rid, None)
                     return True
         return False
 
@@ -153,14 +160,15 @@ class BatchDecodeEngine:
         ALWAYS a new token: an un-preempted request prefills just its
         prompt; a recomputed one replays its emitted tokens teacher-forced,
         so the last position is one past what it had emitted."""
-        tokens = list(req.prompt) + list(req.output)
-        batch = {"tokens": jnp.asarray([tokens], jnp.int32)}
-        state, stats = self.sm.forward_partial(batch, collect_cache=True)
-        assert stats is not None
-        for lid, c in state.caches.items():
-            self.kv.write(req.rid, lid, 0,
-                          np.asarray(c["k"][0]), np.asarray(c["v"][0]))
-        return int(np.argmax(np.asarray(state.logits)[0, -1]))
+        with span("admit", rid=req.rid):
+            tokens = list(req.prompt) + list(req.output)
+            batch = {"tokens": jnp.asarray([tokens], jnp.int32)}
+            state, stats = self.sm.forward_partial(batch, collect_cache=True)
+            assert stats is not None
+            for lid, c in state.caches.items():
+                k, v = self.kv.readback(c["k"][0], c["v"][0])
+                self.kv.write(req.rid, lid, 0, k, v)
+            return int(np.argmax(np.asarray(state.logits)[0, -1]))
 
     # ------------------------------------------------------------ stepping
     def step(self) -> Optional[StepTrace]:
@@ -193,6 +201,9 @@ class BatchDecodeEngine:
                         f"({self.kv.max_pages} x {self.kv.page_tokens} tok)")
                 break
             t0 = time.perf_counter()
+            with self._lock:
+                self.admit_wait_s += t0 - self._queued_at.pop(req.rid)
+                self.admitted += 1
             try:
                 tok = self._prefill(req)
             except SwapError as e:
@@ -222,11 +233,9 @@ class BatchDecodeEngine:
                 with self._lock:
                     if not self._pending:
                         return None
-            tr = StepTrace(self._step_no, [], admitted, retired, [],
-                           self.kv.pages_in_use, 0.0, failed=failed)
-            self.trace.append(tr)
-            self._step_no += 1
-            return tr
+            return self._record(StepTrace(
+                self._step_no, [], admitted, retired, [],
+                self.kv.pages_in_use, 0.0, failed=failed))
 
         # -- grow every sequence by one token; evict from the back of the
         #    priority order when pages / ledger budget run out
@@ -249,6 +258,7 @@ class BatchDecodeEngine:
             self.preemptions += 1
             preempted.append(victim.req.rid)
             with self._lock:
+                self._queued_at[victim.req.rid] = time.perf_counter()
                 self._pending.appendleft(victim.req)
         self._active = survivors
 
@@ -256,7 +266,7 @@ class BatchDecodeEngine:
         if self._active:
             t0 = time.perf_counter()
             rids = [a.req.rid for a in self._active]
-            view = PagedBatchView(self.kv, rids)
+            view = PagedBatchView(self.kv, rids, step=self._step_no)
             pos = np.asarray([self.kv.seq_len(r) - 1 for r in rids], np.int32)
             batch = {"token": jnp.asarray(
                          [[a.req.output[-1]] for a in self._active],
@@ -280,11 +290,16 @@ class BatchDecodeEngine:
         else:
             rids = []
 
-        tr = StepTrace(self._step_no, rids, admitted, retired, preempted,
-                       self.kv.pages_in_use, len(rids) / self.max_batch,
-                       failed=failed)
+        return self._record(StepTrace(
+            self._step_no, rids, admitted, retired, preempted,
+            self.kv.pages_in_use, len(rids) / self.max_batch, failed=failed))
+
+    def _record(self, tr: StepTrace) -> StepTrace:
+        """Close a step: its trace, and the KV counters as of its end, so
+        that ``stats()`` pairs them with the same count of steps."""
         self.trace.append(tr)
         self._step_no += 1
+        self._kv_counters = self.kv.counters()
         return tr
 
     # ------------------------------------------------------------ driving
@@ -328,4 +343,7 @@ class BatchDecodeEngine:
                           / max(self.prefill_s + self.decode_s, 1e-9)),
             "kv_pages_peak": float(max((t.kv_pages for t in self.trace),
                                        default=0)),
+            "admit_wait_s": self.admit_wait_s,
+            "admitted": float(self.admitted),
+            **{f"kv_{k}": float(v) for k, v in self._kv_counters.items()},
         }

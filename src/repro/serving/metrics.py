@@ -67,6 +67,14 @@ METRIC_FAMILIES: List[Tuple[str, str, str]] = [
     ("swapnet_kv_pages_capacity", "KV page-pool capacity by model", "gauge"),
     ("swapnet_kv_page_occupancy", "in_use/capacity (0..1) by model",
      "gauge"),
+    ("swapnet_kv_upload_bytes_total", "Host->device bytes of KV pool "
+     "re-uploads by model", "counter"),
+    ("swapnet_kv_device_pool_bytes", "Device bytes held by the cached KV "
+     "pool copies by model (not charged to the ledger)", "gauge"),
+    ("swapnet_admit_wait_seconds_total", "Seconds generate requests waited "
+     "from queueing to admission, by model", "counter"),
+    ("swapnet_admitted_total", "Generate admissions by model "
+     "(re-admissions after preemption included)", "counter"),
     ("swapnet_http_requests_total", "Control-plane HTTP requests by "
      "endpoint", "counter"),
 ]
@@ -196,6 +204,14 @@ class MetricsRegistry:
                      float(kv.max_pages)),
                     ("swapnet_kv_page_occupancy", labels,
                      float(kv.pages_in_use) / max(kv.max_pages, 1)),
+                    ("swapnet_kv_upload_bytes_total", labels,
+                     float(kv.upload_bytes)),
+                    ("swapnet_kv_device_pool_bytes", labels,
+                     float(kv.device_pool_bytes)),
+                    ("swapnet_admit_wait_seconds_total", labels,
+                     engine.admit_wait_s),
+                    ("swapnet_admitted_total", labels,
+                     float(engine.admitted)),
                 ]
         sched = self.scheduler
         if sched is not None:

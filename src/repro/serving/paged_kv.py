@@ -23,18 +23,30 @@ Pools are host numpy buffers mutated in place (the decode loop is eager, one
 host->device upload per layer per batched step); the pool capacity is
 preallocated but the ledger only carries LOGICALLY allocated pages, mirroring
 how the weight ledger carries resident blocks, not the store file.
+
+The host round trip is counted where it happens: ``upload_bytes`` (pool
+re-uploads), ``host_s`` (seconds in the K/V copy to host, the host writes
+and the re-uploads), and the gauge ``device_pool_bytes`` (the cached device
+copies of the pools, which the ledger does not charge). Each step is also a
+``swapnet.kv.*`` profiler span (``repro.tracing``); the wait for the device
+programs that make the new K/V is ``swapnet.kv.readback.wait``, outside
+``host_s``, since it waits on the device queue and not on the pools.
 """
 from __future__ import annotations
 
 import threading
-from typing import Dict, List, Optional, Sequence, Tuple
+import time
+from contextlib import contextmanager
+from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.core.swap_engine import MemoryLedger
 from repro.kernels import ops
+from repro.tracing import span
 
 __all__ = ["PagedKVCache", "PagedBatchView", "page_bytes_for"]
 
@@ -83,6 +95,13 @@ class PagedKVCache:
         self._lock = threading.Lock()
         self._dirty = [True] * cfg.n_layers
         self._dev: List[Optional[Tuple]] = [None] * cfg.n_layers
+        # round-trip counters: written only by the thread stepping the
+        # batch engine (one step at a time), read by anyone
+        self.upload_bytes = 0            # host -> device pool re-uploads
+        # seconds in the host round trip; temporary: the benchmark reads it
+        # until its trace reader keeps the swapnet.kv.* spans, whose union
+        # is the same number, and then it goes with ``_host``'s timing
+        self.host_s = 0.0
 
     @classmethod
     def for_budget(cls, cfg: ModelConfig, ledger: MemoryLedger,
@@ -151,6 +170,25 @@ class PagedKVCache:
         with self._lock:
             return self._len[seq_id]
 
+    # ------------------------------------------------------------ host
+    @contextmanager
+    def _host(self, name: str) -> Iterator[None]:
+        """One step of the host round trip: a ``swapnet.<name>`` span, its
+        seconds added to ``host_s``."""
+        t0 = time.perf_counter()
+        with span(name):
+            yield
+        self.host_s += time.perf_counter() - t0
+
+    def readback(self, *arrays) -> List[np.ndarray]:
+        """Device K/V to host numpy. The wait for the programs that make
+        them (the device queue, and the weight transfers those programs
+        read) is ``kv.readback.wait``; only the copy is ``kv.readback``."""
+        with span("kv.readback.wait"):
+            jax.block_until_ready(arrays)
+        with self._host("kv.readback"):
+            return [np.asarray(a) for a in arrays]
+
     # ------------------------------------------------------------ tokens
     def write(self, seq_id, layer: int, start: int, k: np.ndarray,
               v: np.ndarray) -> None:
@@ -162,15 +200,16 @@ class PagedKVCache:
                 (start, k.shape, self._len[seq_id])
         T = self.page_tokens
         kp, vp = self.k_pools[layer], self.v_pools[layer]
-        t = 0
-        while t < k.shape[0]:
-            pos = start + t
-            pid = pages[pos // T]
-            slot = pos % T
-            n = min(T - slot, k.shape[0] - t)
-            kp[:, pid, slot:slot + n] = k[t:t + n].swapaxes(0, 1)
-            vp[:, pid, slot:slot + n] = v[t:t + n].swapaxes(0, 1)
-            t += n
+        with self._host("kv.write"):
+            t = 0
+            while t < k.shape[0]:
+                pos = start + t
+                pid = pages[pos // T]
+                slot = pos % T
+                n = min(T - slot, k.shape[0] - t)
+                kp[:, pid, slot:slot + n] = k[t:t + n].swapaxes(0, 1)
+                vp[:, pid, slot:slot + n] = v[t:t + n].swapaxes(0, 1)
+                t += n
         self._dirty[layer] = True
 
     def last_slots(self, seq_ids: Sequence) -> Tuple[np.ndarray, np.ndarray]:
@@ -189,8 +228,9 @@ class PagedKVCache:
         """Scatter one token per sequence ([B, KV, hd]) into pool rows
         addressed by ``last_slots`` — the vectorized decode-step write (one
         fancy-index assignment instead of B ``write`` calls per layer)."""
-        self.k_pools[layer][:, pids, slots] = k.swapaxes(0, 1)
-        self.v_pools[layer][:, pids, slots] = v.swapaxes(0, 1)
+        with self._host("kv.write"):
+            self.k_pools[layer][:, pids, slots] = k.swapaxes(0, 1)
+            self.v_pools[layer][:, pids, slots] = v.swapaxes(0, 1)
         self._dirty[layer] = True
 
     # ------------------------------------------------------------ views
@@ -210,10 +250,25 @@ class PagedKVCache:
         """The layer's page pools as device arrays (re-uploaded only after a
         host-side write dirtied the layer)."""
         if self._dirty[layer] or self._dev[layer] is None:
-            self._dev[layer] = (jnp.asarray(self.k_pools[layer]),
-                                jnp.asarray(self.v_pools[layer]))
+            kp, vp = self.k_pools[layer], self.v_pools[layer]
+            with self._host("kv.upload"):
+                self._dev[layer] = (jnp.asarray(kp), jnp.asarray(vp))
             self._dirty[layer] = False
+            self.upload_bytes += kp.nbytes + vp.nbytes
         return self._dev[layer]
+
+    @property
+    def device_pool_bytes(self) -> int:
+        """Bytes the cached device copies of the pools hold now (outside
+        the ledger, which charges allocated pages only)."""
+        return sum(k.nbytes + v.nbytes for k, v in
+                   (d for d in self._dev if d is not None))
+
+    def counters(self) -> Dict[str, float]:
+        """The host round trip's counters and the device-pool gauge."""
+        return {"upload_bytes": self.upload_bytes,
+                "device_pool_bytes": self.device_pool_bytes,
+                "host_s": self.host_s}
 
     # ------------------------------------------------------------ stats
     @property
@@ -258,9 +313,10 @@ class PagedBatchView:
     shared by all layers of the step.
     """
 
-    def __init__(self, kv: PagedKVCache, seq_ids: Sequence):
+    def __init__(self, kv: PagedKVCache, seq_ids: Sequence, step: int = -1):
         self.kv = kv
         self.seq_ids = list(seq_ids)
+        self.step = step        # engine step number (-1 outside one), traced
         pt, sl = kv.page_table(self.seq_ids)
         self._host_lens = sl
         # every layer writes the SAME (page, slot) per sequence this step —
@@ -274,8 +330,8 @@ class PagedBatchView:
                softcap: Optional[float] = None):
         """Append this layer's new K/V ([B, KV, hd]) to each sequence's
         pages, then attend q ([B, H, hd]) through the page table."""
-        self.kv.write_rows(layer, self._w_pids, self._w_slots,
-                           np.asarray(k_new), np.asarray(v_new))
+        k, v = self.kv.readback(k_new, v_new)
+        self.kv.write_rows(layer, self._w_pids, self._w_slots, k, v)
         kp, vp = self.kv.device_pools(layer)
         return ops.paged_attention(q, kp, vp, self.page_table, self.seq_lens,
                                    scale=scale, window=window, softcap=softcap)

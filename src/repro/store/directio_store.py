@@ -47,6 +47,7 @@ import jax
 import numpy as np
 
 from repro.store.base import BlockStore, UnitRead
+from repro.tracing import span
 
 ALIGNMENT = 4096        # logical block size bound: address, offset, count
 
@@ -174,19 +175,22 @@ class DirectIOStore(BlockStore):
         if n == 0:
             return self._empty_unit(name)
         aligned = _align_up(n)
-        t0 = time.perf_counter()
-        buf = self.arena.take(aligned)
-        self._read_into(self._path(name), buf)
-        # digest covers the padded file (what storage actually delivered)
-        self._verify_payload(name, buf)
-        t1 = time.perf_counter()
-        host_tree = assemble_np(skel, buf[:n])     # views: zero copy
-        t2 = time.perf_counter()
-        # the device put MUST copy out of the arena before the buffer
-        # rotates back around — block here (loader thread) to guarantee it
-        dev = jax.device_put(host_tree)            # batched puts
-        jax.block_until_ready(dev)
-        t3 = time.perf_counter()
+        with span("swap.read", unit=name):
+            t0 = time.perf_counter()
+            buf = self.arena.take(aligned)
+            self._read_into(self._path(name), buf)
+            # digest covers the padded file (what storage actually delivered)
+            self._verify_payload(name, buf)
+            t1 = time.perf_counter()
+        with span("swap.unpack", unit=name):
+            host_tree = assemble_np(skel, buf[:n])     # views: zero copy
+            t2 = time.perf_counter()
+        with span("swap.dispatch", unit=name):
+            # the device put MUST copy out of the arena before the buffer
+            # rotates back around — block here (loader thread) to guarantee
+            dev = jax.device_put(host_tree)            # batched puts
+            jax.block_until_ready(dev)
+            t3 = time.perf_counter()
         stages = (("read", t0, t1), ("unpack", t1, t2), ("dispatch", t2, t3))
         return UnitRead(dev, aligned, n, t1 - t0, t3 - t1, stages=stages)
 

@@ -17,6 +17,7 @@ import jax
 import numpy as np
 
 from repro.store.base import BlockStore, UnitRead
+from repro.tracing import span
 
 
 class MmapStore(BlockStore):
@@ -42,24 +43,24 @@ class MmapStore(BlockStore):
         n = skel.nbytes
         if n == 0:
             return self._empty_unit(name)
-        t0 = time.perf_counter()
-        buf = np.memmap(self._path(name), dtype=np.uint8, mode="r")
-        # verify (opt-in) trades mmap's lazy page-in for integrity: the CRC
-        # pass faults every page on the loader thread, so a corrupt unit is
-        # rejected here instead of being device-put and silently computed on
-        self._verify_payload(name, buf)
-        t1 = time.perf_counter()
-        if self.assembly == "dummy":
-            host_tree = assemble_dummy(skel, buf)      # dummy-model copies
+        with span("swap.read", unit=name):
+            t0 = time.perf_counter()
+            buf = np.memmap(self._path(name), dtype=np.uint8, mode="r")
+            # verify (opt-in) trades mmap's lazy page-in for integrity: the
+            # CRC pass faults every page on the loader thread, so a corrupt
+            # unit is rejected here instead of being device-put and silently
+            # computed on
+            self._verify_payload(name, buf)
+            t1 = time.perf_counter()
+        dummy = self.assembly == "dummy"
+        with span("swap.unpack", unit=name):
+            host_tree = (assemble_dummy(skel, buf) if dummy  # dummy-model copies
+                         else assemble_np(skel, buf))        # views: zero copy
             t2 = time.perf_counter()
-            dev = jax.device_put(host_tree)       # batched puts
-            extra = 2 * n
-        else:
-            host_tree = assemble_np(skel, buf)         # views: zero copy
-            t2 = time.perf_counter()
+        with span("swap.dispatch", unit=name):
             dev = jax.device_put(host_tree)       # the one (batched) DMA
-            extra = n
-        t3 = time.perf_counter()
+            t3 = time.perf_counter()
+        extra = 2 * n if dummy else n
         # mmap blurs the read stage: the memmap open is O(1) and the actual
         # page-ins fault lazily inside the device put, so "dispatch" carries
         # the storage traffic too (documented in docs/BENCHMARKS.md).

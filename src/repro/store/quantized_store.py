@@ -83,6 +83,7 @@ import numpy as np
 
 from repro.kernels.qtensor import FUSED_WEIGHT_KEYS
 from repro.store.base import BlockStore, UnitRead
+from repro.tracing import span
 
 MIN_QUANT_SIZE = 1024       # elements; smaller leaves are stored raw
 
@@ -256,75 +257,80 @@ class QuantizedStore(BlockStore):
             return self._empty_unit(name)
         meta = self._qmeta[name]
         lazy = not self.eager
-        t0 = time.perf_counter()
-        # read: ONE sequential buffered read forces the whole carrier payload
-        # host-resident on the loader thread — a memmap here would defer the
-        # storage traffic to page faults inside the device puts below, where
-        # it can no longer overlap the executor (module docstring, "Pipeline
-        # contract").
-        buf = np.fromfile(self._path(name), dtype=np.uint8)
-        # integrity over the CARRIER bytes: a flipped nibble in a packed-int4
-        # payload is caught here, never dequantized into wrong weights
-        self._verify_payload(name, buf)
-        t1 = time.perf_counter()
-        # unpack: host-side work over the payload. Raw and streamable leaves
-        # are pure views; in lazy mode the quantized leaves the fused kernel
-        # CANNOT stream dequantize here in numpy — host FLOPs on the
-        # otherwise-idle loader core, one device put per leaf, instead of a
-        # per-leaf device-op storm or dequant-at-use inside executor compute.
-        host: list = []
-        for ql in meta.leaves:
-            dt = jnp.dtype(ql.dtype)
-            if ql.scale_offset < 0:            # raw leaf
-                host.append((ql, buf[ql.offset:ql.offset + ql.nbytes]
-                             .view(dt.type).reshape(ql.shape), None))
-                continue
-            qv = buf[ql.offset:ql.offset + ql.nbytes] \
-                .view(np.int8).reshape(-1, ql.cols)
-            sv = buf[ql.scale_offset:ql.scale_offset + 4 * ql.cols] \
-                .view(np.float32)
-            if lazy and not ql.fusable:
-                vals = unpack_int4(qv, ql.rows) if ql.bits == 4 else qv
-                # one fused multiply pass (int8 x scales -> fp32 out); the
-                # naive astype()*astype() chain costs 3 full-size copies
-                fp = np.multiply(vals, sv[None, :], dtype=np.float32)
-                if dt.type is not np.float32:
-                    fp = fp.astype(dt.type)
-                host.append((ql, fp.reshape(ql.shape), None))
-            else:
-                host.append((ql, qv, sv))
-        t2 = time.perf_counter()
-        # dispatch: host -> device puts (eager mode keeps the seed's
-        # on-device Pallas dequant — it rides the H2D transfer), flushed
-        # HERE so the executor never inherits loader work. All leaves go up
-        # in ONE batched jax.device_put — per-call dispatch overhead
-        # (~100-200us) over dozens of leaves is the single largest loader
-        # cost after the dequant itself
-        arrs: list = []
-        for _, qv, sv in host:
-            arrs.append(qv)
-            if sv is not None:
-                arrs.append(sv)
-        dev = iter(jax.device_put(arrs))
-        leaves = []
-        qbytes = 0
-        for ql, qv, sv in host:
-            q = next(dev)
-            if sv is None:
-                leaves.append(q)
-                continue
-            s = next(dev)
-            if lazy:                           # fused path: stay quantized
-                leaves.append(QuantizedTensor(q, s, ql.shape, ql.dtype,
-                                              ql.bits))
-                qbytes += ql.nbytes + 4 * ql.cols
-                continue
-            vals = unpack_int4_ref(q, ql.rows) if ql.bits == 4 else q
-            leaves.append(dequant_int8(vals, s, jnp.dtype(ql.dtype).type)
-                          .reshape(ql.shape))
-        tree = jax.tree.unflatten(skel.treedef, leaves)
-        jax.block_until_ready(tree)
-        t3 = time.perf_counter()
+        with span("swap.read", unit=name):
+            t0 = time.perf_counter()
+            # read: ONE sequential buffered read forces the whole carrier
+            # payload host-resident on the loader thread — a memmap here
+            # would defer the storage traffic to page faults inside the
+            # device puts below, where it can no longer overlap the executor
+            # (module docstring, "Pipeline contract").
+            buf = np.fromfile(self._path(name), dtype=np.uint8)
+            # integrity over the CARRIER bytes: a flipped nibble in a
+            # packed-int4 payload is caught here, never dequantized into
+            # wrong weights
+            self._verify_payload(name, buf)
+            t1 = time.perf_counter()
+        with span("swap.unpack", unit=name):
+            # unpack: host-side work over the payload. Raw and streamable
+            # leaves are pure views; in lazy mode the quantized leaves the
+            # fused kernel CANNOT stream dequantize here in numpy — host
+            # FLOPs on the otherwise-idle loader core, one device put per
+            # leaf, instead of a per-leaf device-op storm or dequant-at-use
+            # inside executor compute.
+            host: list = []
+            for ql in meta.leaves:
+                dt = jnp.dtype(ql.dtype)
+                if ql.scale_offset < 0:            # raw leaf
+                    host.append((ql, buf[ql.offset:ql.offset + ql.nbytes]
+                                 .view(dt.type).reshape(ql.shape), None))
+                    continue
+                qv = buf[ql.offset:ql.offset + ql.nbytes] \
+                    .view(np.int8).reshape(-1, ql.cols)
+                sv = buf[ql.scale_offset:ql.scale_offset + 4 * ql.cols] \
+                    .view(np.float32)
+                if lazy and not ql.fusable:
+                    vals = unpack_int4(qv, ql.rows) if ql.bits == 4 else qv
+                    # one fused multiply pass (int8 x scales -> fp32 out);
+                    # the naive astype()*astype() chain costs 3 full copies
+                    fp = np.multiply(vals, sv[None, :], dtype=np.float32)
+                    if dt.type is not np.float32:
+                        fp = fp.astype(dt.type)
+                    host.append((ql, fp.reshape(ql.shape), None))
+                else:
+                    host.append((ql, qv, sv))
+            t2 = time.perf_counter()
+        with span("swap.dispatch", unit=name):
+            # dispatch: host -> device puts (eager mode keeps the seed's
+            # on-device Pallas dequant — it rides the H2D transfer), flushed
+            # HERE so the executor never inherits loader work. All leaves go
+            # up in ONE batched jax.device_put — per-call dispatch overhead
+            # (~100-200us) over dozens of leaves is the single largest loader
+            # cost after the dequant itself
+            arrs: list = []
+            for _, qv, sv in host:
+                arrs.append(qv)
+                if sv is not None:
+                    arrs.append(sv)
+            dev = iter(jax.device_put(arrs))
+            leaves = []
+            qbytes = 0
+            for ql, qv, sv in host:
+                q = next(dev)
+                if sv is None:
+                    leaves.append(q)
+                    continue
+                s = next(dev)
+                if lazy:                           # fused path: stay quantized
+                    leaves.append(QuantizedTensor(q, s, ql.shape, ql.dtype,
+                                                  ql.bits))
+                    qbytes += ql.nbytes + 4 * ql.cols
+                    continue
+                vals = unpack_int4_ref(q, ql.rows) if ql.bits == 4 else q
+                leaves.append(dequant_int8(vals, s, jnp.dtype(ql.dtype).type)
+                              .reshape(ql.shape))
+            tree = jax.tree.unflatten(skel.treedef, leaves)
+            jax.block_until_ready(tree)
+            t3 = time.perf_counter()
         stored = meta.stored_nbytes
         ledger = meta.resident_lazy if lazy else stored
         stages = (("read", t0, t1), ("unpack", t1, t2), ("dispatch", t2, t3))

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.store.base import BlockStore, UnitRead
+from repro.tracing import span
 
 
 class RawIOStore(BlockStore):
@@ -40,20 +41,21 @@ class RawIOStore(BlockStore):
         n = skel.nbytes
         if n == 0:
             return self._empty_unit(name)
-        t0 = time.perf_counter()
-        with open(self._path(name), "rb") as fh:       # read(): page-cache copy
-            raw = fh.read()
-        staged = np.frombuffer(raw, np.uint8).copy()   # staging copy
-        self._verify_payload(name, staged)
-        t1 = time.perf_counter()
-        host_tree = assemble_np(skel, staged)
-        t2 = time.perf_counter()
-        dev = jax.device_put(host_tree)                # device transfer
-        if self.gpu_dispatch:
-            dev = jax.tree.map(jnp.array, dev)         # dispatch copy (.to('cuda'))
-            extra = 3 * n
-        else:
-            extra = 2 * n
-        t3 = time.perf_counter()
+        with span("swap.read", unit=name):
+            t0 = time.perf_counter()
+            with open(self._path(name), "rb") as fh:   # read(): page-cache copy
+                raw = fh.read()
+            staged = np.frombuffer(raw, np.uint8).copy()   # staging copy
+            self._verify_payload(name, staged)
+            t1 = time.perf_counter()
+        with span("swap.unpack", unit=name):
+            host_tree = assemble_np(skel, staged)
+            t2 = time.perf_counter()
+        with span("swap.dispatch", unit=name):
+            dev = jax.device_put(host_tree)            # device transfer
+            if self.gpu_dispatch:
+                dev = jax.tree.map(jnp.array, dev)     # dispatch copy (.to('cuda'))
+            t3 = time.perf_counter()
+        extra = (3 if self.gpu_dispatch else 2) * n
         stages = (("read", t0, t1), ("unpack", t1, t2), ("dispatch", t2, t3))
         return UnitRead(dev, n, extra, t1 - t0, t3 - t1, stages=stages)

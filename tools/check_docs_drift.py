@@ -16,10 +16,11 @@ Checks, over README.md and docs/*.md:
     profile in ``repro.config.PROFILES`` is documented in README.md AND
     docs/ARCHITECTURE.md, every ``profile:<name>`` / ``SWAPNET_*`` token
     the docs mention exists in code, every documented dotted config key
-    (``runtime.budget_mb`` style) is a real ``ServeConfig`` field, and the
+    (``runtime.budget_mb`` style) is a real ``ServeConfig`` field, the
     HTTP endpoint tables match ``repro.serving.control_plane.ENDPOINTS``
     exactly (both directions: undocumented endpoint = drift, documented
-    ghost endpoint = drift).
+    ghost endpoint = drift), and the ``/metrics`` family table matches
+    ``repro.serving.metrics.METRIC_FAMILIES`` (both directions).
 
 Docs rot silently: a rename refactor updates every import but no grep hits
 the prose. This runs in CI next to the test suite so the rename PR is the
@@ -119,8 +120,8 @@ def looks_like_path(tok: str) -> bool:
 
 
 def check_serving_config(readme: str, arch: str) -> list[str]:
-    """Profiles, config keys, env vars, and HTTP endpoints: docs <-> code,
-    both directions."""
+    """Profiles, config keys, env vars, HTTP endpoints and metric
+    families: docs <-> code, both directions."""
     from repro.config import ENV_PREFIX, config_fields, profile_names
     from repro.serving.control_plane import ENDPOINTS
     errors: list[str] = []
@@ -154,6 +155,20 @@ def check_serving_config(readme: str, arch: str) -> list[str]:
                 # (module-map lines like `runtime.py` are paths, not keys)
                 errors.append(f"{name}: config key `{tok}` is not a "
                               f"ServeConfig field")
+
+    # metric families: every `swapnet_*` name the docs use is exported,
+    # and the ARCHITECTURE.md table lists exactly the exported families
+    from repro.serving.metrics import METRIC_FAMILIES
+    families = {name for name, _, _ in METRIC_FAMILIES}
+    for name, text in [("README.md", readme), ("docs/ARCHITECTURE.md", arch)]:
+        for fam in sorted(set(re.findall(r"\bswapnet_[a-z_]+\b", text))
+                          - families):
+            errors.append(f"{name}: metric `{fam}` is not in "
+                          f"serving.metrics.METRIC_FAMILIES")
+    table = set(re.findall(r"^\| `(swapnet_[a-z_]+)` \|", arch, re.M))
+    for fam in sorted(families - table):
+        errors.append(f"docs/ARCHITECTURE.md: metric family `{fam}` "
+                      f"(METRIC_FAMILIES) is missing from the /metrics table")
 
     # endpoint tables: exact two-way match against the code's contract
     code_eps = {(meth, path) for meth, path in ENDPOINTS}
