@@ -205,13 +205,17 @@ apply_layer_jit = jax.jit(apply_layer, static_argnums=(0, 1, 5, 8))
 
 @functools.partial(jax.jit, static_argnums=(0,))
 def _paged_qkv(cfg: ModelConfig, p: dict, x: jax.Array, positions):
+    """The decode token's q [B, H, hd] and k, v [B, KV, hd]."""
     h = rms_norm(x, p["ln1"], cfg.norm_eps, plus_one=cfg.post_norms)
-    return attn_mod.gqa_qkv(cfg, p["attn"], h, positions)
+    q, k, v = attn_mod.gqa_qkv(cfg, p["attn"], h, positions)
+    return q[:, 0], k[:, 0], v[:, 0]
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1))
 def _paged_out(cfg: ModelConfig, kind: str, p: dict, x: jax.Array,
                attn: jax.Array) -> jax.Array:
+    """The rest of the layer after paged attention: ``attn`` [B, H, hd]
+    (or [B, 1, H, hd]) through ``wo``, the residual and the FFN."""
     B, S, _ = x.shape
     a_out = linear(attn.reshape(B, S, -1).astype(x.dtype), p["attn"]["wo"])
     return _block_ffn(cfg, kind, p, x, a_out)[0]
@@ -223,17 +227,17 @@ def apply_layer_paged(cfg: ModelConfig, kind: str, p: dict, x: jax.Array,
     """One decode token per sequence ([B, 1, D]) through the paged KV cache.
 
     ``paged`` is a layer-bound attend hook (``PagedBatchView.bind``): it
-    appends the new K/V to each sequence's pages on the host, then runs
+    appends the new K/V to each sequence's pages on the device, then runs
     paged attention through the page table. The projections before it and
-    the FFN after it are compiled programs, like :data:`apply_layer_jit`.
-    Returns the layer's output x."""
+    the FFN after it are compiled programs, like :data:`apply_layer_jit`,
+    and nothing between the four waits for the device or slices on the
+    host. Returns the layer's output x."""
     assert x.shape[1] == 1, "paged attention is the single-token decode path"
     q, k, v = _paged_qkv(cfg, p, x, positions)
-    out = paged.attend(q[:, 0], k[:, 0], v[:, 0],
-                       scale=attn_mod.attn_scale(cfg),
+    out = paged.attend(q, k, v, scale=attn_mod.attn_scale(cfg),
                        window=attn_mod.paged_window(cfg, is_local),
                        softcap=cfg.attn_logit_softcap)
-    return _paged_out(cfg, kind, p, x, out[:, None])
+    return _paged_out(cfg, kind, p, x, out)
 
 
 # ------------------------------------------------------------------ stack

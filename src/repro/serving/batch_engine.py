@@ -156,18 +156,19 @@ class BatchDecodeEngine:
 
     def _prefill(self, req: Request) -> int:
         """Swapped prefill over prompt + already-emitted output (recompute
-        path), K/V seeded into the page pool. Returns the argmax token —
-        ALWAYS a new token: an un-preempted request prefills just its
-        prompt; a recomputed one replays its emitted tokens teacher-forced,
-        so the last position is one past what it had emitted."""
+        path), K/V seeded into the page pool on the device. Returns the
+        argmax token — ALWAYS a new token: an un-preempted request prefills
+        just its prompt; a recomputed one replays its emitted tokens
+        teacher-forced, so the last position is one past what it had
+        emitted."""
         with span("admit", rid=req.rid):
             tokens = list(req.prompt) + list(req.output)
             batch = {"tokens": jnp.asarray([tokens], jnp.int32)}
             state, stats = self.sm.forward_partial(batch, collect_cache=True)
             assert stats is not None
+            rows = self.kv.rows(req.rid, 0, len(tokens))
             for lid, c in state.caches.items():
-                k, v = self.kv.readback(c["k"][0], c["v"][0])
-                self.kv.write(req.rid, lid, 0, k, v)
+                self.kv.append(lid, rows, c["k"], c["v"])
             return int(np.argmax(np.asarray(state.logits)[0, -1]))
 
     # ------------------------------------------------------------ stepping

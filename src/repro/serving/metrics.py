@@ -67,10 +67,12 @@ METRIC_FAMILIES: List[Tuple[str, str, str]] = [
     ("swapnet_kv_pages_capacity", "KV page-pool capacity by model", "gauge"),
     ("swapnet_kv_page_occupancy", "in_use/capacity (0..1) by model",
      "gauge"),
+    ("swapnet_kv_rows_written_total", "Token rows appended to the KV "
+     "pools on the device, summed over layers, by model", "counter"),
     ("swapnet_kv_upload_bytes_total", "Host->device bytes of KV pool "
-     "re-uploads by model", "counter"),
-    ("swapnet_kv_device_pool_bytes", "Device bytes held by the cached KV "
-     "pool copies by model (not charged to the ledger)", "gauge"),
+     "uploads by model (0: the pools stay on the device)", "counter"),
+    ("swapnet_kv_device_pool_bytes", "Device bytes held by the KV pools "
+     "by model (not charged to the ledger)", "gauge"),
     ("swapnet_admit_wait_seconds_total", "Seconds generate requests waited "
      "from queueing to admission, by model", "counter"),
     ("swapnet_admitted_total", "Generate admissions by model "
@@ -204,6 +206,8 @@ class MetricsRegistry:
                      float(kv.max_pages)),
                     ("swapnet_kv_page_occupancy", labels,
                      float(kv.pages_in_use) / max(kv.max_pages, 1)),
+                    ("swapnet_kv_rows_written_total", labels,
+                     float(kv.rows_written)),
                     ("swapnet_kv_upload_bytes_total", labels,
                      float(kv.upload_bytes)),
                     ("swapnet_kv_device_pool_bytes", labels,

@@ -19,21 +19,24 @@ both the free list and the ledger untouched, and the batch engine answers it
 with preemption-by-recomputation (free the youngest sequence's pages,
 requeue it; greedy decode recomputes bit-identically).
 
-Pools are host numpy buffers mutated in place (the decode loop is eager, one
-host->device upload per layer per batched step); the pool capacity is
-preallocated but the ledger only carries LOGICALLY allocated pages, mirroring
-how the weight ledger carries resident blocks, not the store file.
+Each layer's K and V pools are device arrays, the only copy, updated in
+place: a compiled scatter under buffer donation (``_kv_append``) writes a
+decode step's new rows, or a prefill's whole prompt on admission, so no
+K/V crosses to the host and nothing waits on the device between a layer's
+programs. The pool capacity is preallocated but the ledger only carries
+LOGICALLY allocated pages, mirroring how the weight ledger carries resident
+blocks, not the store file.
 
-The host round trip is counted where it happens: ``upload_bytes`` (pool
-re-uploads), ``host_s`` (seconds in the K/V copy to host, the host writes
-and the re-uploads), and the gauge ``device_pool_bytes`` (the cached device
-copies of the pools, which the ledger does not charge). Each step is also a
-``swapnet.kv.*`` profiler span (``repro.tracing``); the wait for the device
-programs that make the new K/V is ``swapnet.kv.readback.wait``, outside
-``host_s``, since it waits on the device queue and not on the pools.
+Counters: ``rows_written`` (token rows appended on the device, summed over
+layers), ``host_s`` (seconds dispatching the appends, span
+``swapnet.kv.write``, ``repro.tracing``), ``upload_bytes`` (host->device
+pool bytes, 0 since the pools never leave the device) and the gauge
+``device_pool_bytes`` (the pools' device bytes, which the ledger does not
+charge).
 """
 from __future__ import annotations
 
+import functools
 import threading
 import time
 from contextlib import contextmanager
@@ -49,6 +52,21 @@ from repro.kernels import ops
 from repro.tracing import span
 
 __all__ = ["PagedKVCache", "PagedBatchView", "page_bytes_for"]
+
+
+@functools.partial(jax.jit, donate_argnums=(0, 1))
+def _kv_append(k_pool, v_pool, rows, k, v):
+    """Scatter token rows into one layer's [KV, P, T, hd] pools in place:
+    ``rows`` [2, N] holds each row's page id and slot, ``k``/``v`` hold the
+    N rows of [KV, hd] under any leading shape ([B, KV, hd] from a decode
+    step, [1, S, KV, hd] from a prefill). Rows are distinct: each (page,
+    slot) belongs to one token of one sequence."""
+    KV, hd = k_pool.shape[0], k_pool.shape[-1]
+
+    def put(pool, x):
+        x = x.reshape(-1, KV, hd).swapaxes(0, 1).astype(pool.dtype)
+        return pool.at[:, rows[0], rows[1]].set(x, unique_indices=True)
+    return put(k_pool, k), put(v_pool, v)
 
 
 def page_bytes_for(cfg: ModelConfig, page_tokens: int) -> int:
@@ -87,20 +105,19 @@ class PagedKVCache:
         # a real (masked) page. KV heads lead ([KV, P, T, hd]): the kernel
         # streams one (page_tokens, hd) tile per (kv head, page)
         shape = (KV, self.max_pages + 1, self.page_tokens, hd)
-        self.k_pools = [np.zeros(shape, dt) for _ in range(cfg.n_layers)]
-        self.v_pools = [np.zeros(shape, dt) for _ in range(cfg.n_layers)]
+        self.k_pools = [jnp.zeros(shape, dt) for _ in range(cfg.n_layers)]
+        self.v_pools = [jnp.zeros(shape, dt) for _ in range(cfg.n_layers)]
         self._free: List[int] = list(range(self.max_pages, 0, -1))
         self._pages: Dict[object, List[int]] = {}
         self._len: Dict[object, int] = {}
         self._lock = threading.Lock()
-        self._dirty = [True] * cfg.n_layers
-        self._dev: List[Optional[Tuple]] = [None] * cfg.n_layers
-        # round-trip counters: written only by the thread stepping the
-        # batch engine (one step at a time), read by anyone
-        self.upload_bytes = 0            # host -> device pool re-uploads
-        # seconds in the host round trip; temporary: the benchmark reads it
-        # until its trace reader keeps the swapnet.kv.* spans, whose union
-        # is the same number, and then it goes with ``_host``'s timing
+        # the pools and these counters are written only by the thread
+        # stepping the batch engine (one step at a time), read by anyone
+        self.rows_written = 0            # token rows appended, all layers
+        self.upload_bytes = 0            # host -> device pool bytes: none
+        # seconds dispatching the appends; temporary: the benchmark reads
+        # it until its trace reader keeps the swapnet.kv.* spans, whose
+        # union is the same number, and then it goes with ``_host``'s timing
         self.host_s = 0.0
 
     @classmethod
@@ -173,65 +190,46 @@ class PagedKVCache:
     # ------------------------------------------------------------ host
     @contextmanager
     def _host(self, name: str) -> Iterator[None]:
-        """One step of the host round trip: a ``swapnet.<name>`` span, its
-        seconds added to ``host_s``."""
+        """Host time of the KV path: a ``swapnet.<name>`` span, its seconds
+        added to ``host_s``."""
         t0 = time.perf_counter()
         with span(name):
             yield
         self.host_s += time.perf_counter() - t0
 
-    def readback(self, *arrays) -> List[np.ndarray]:
-        """Device K/V to host numpy. The wait for the programs that make
-        them (the device queue, and the weight transfers those programs
-        read) is ``kv.readback.wait``; only the copy is ``kv.readback``."""
-        with span("kv.readback.wait"):
-            jax.block_until_ready(arrays)
-        with self._host("kv.readback"):
-            return [np.asarray(a) for a in arrays]
-
     # ------------------------------------------------------------ tokens
-    def write(self, seq_id, layer: int, start: int, k: np.ndarray,
-              v: np.ndarray) -> None:
-        """Scatter ``k``/``v`` [S, KV, hd] into the sequence's pages at token
-        positions ``start .. start+S`` (positions must be allocated)."""
-        with self._lock:
-            pages = self._pages[seq_id]
-            assert start + k.shape[0] <= self._len[seq_id], \
-                (start, k.shape, self._len[seq_id])
+    def rows(self, seq_id, start: int, n: int) -> jax.Array:
+        """[2, n] int32 on the device: the page id and slot of the
+        sequence's token positions ``start .. start+n`` (which must be
+        allocated), for :meth:`append`."""
         T = self.page_tokens
-        kp, vp = self.k_pools[layer], self.v_pools[layer]
-        with self._host("kv.write"):
-            t = 0
-            while t < k.shape[0]:
-                pos = start + t
-                pid = pages[pos // T]
-                slot = pos % T
-                n = min(T - slot, k.shape[0] - t)
-                kp[:, pid, slot:slot + n] = k[t:t + n].swapaxes(0, 1)
-                vp[:, pid, slot:slot + n] = v[t:t + n].swapaxes(0, 1)
-                t += n
-        self._dirty[layer] = True
+        pos = np.arange(start, start + n)
+        with self._lock:
+            assert start + n <= self._len[seq_id], \
+                (start, n, self._len[seq_id])
+            pids = np.asarray(self._pages[seq_id], np.int32)[pos // T]
+        return jnp.asarray(np.stack([pids, (pos % T).astype(np.int32)]))
 
-    def last_slots(self, seq_ids: Sequence) -> Tuple[np.ndarray, np.ndarray]:
-        """(page_ids [B], slots [B]) addressing each sequence's LAST token —
-        the decode-step write position, computed once and reused by every
-        layer's batched scatter (``write_rows``)."""
+    def last_rows(self, seq_ids: Sequence) -> jax.Array:
+        """[2, B] int32 on the device addressing each sequence's LAST token:
+        the decode step's write position, uploaded once and reused by every
+        layer's append."""
         T = self.page_tokens
         with self._lock:
             pos = [self._len[s] - 1 for s in seq_ids]
             pids = [self._pages[s][p // T] for s, p in zip(seq_ids, pos)]
-        return (np.asarray(pids, np.int32),
-                np.asarray([p % T for p in pos], np.int32))
+        return jnp.asarray(np.asarray([pids, [p % T for p in pos]],
+                                      np.int32))
 
-    def write_rows(self, layer: int, pids: np.ndarray, slots: np.ndarray,
-                   k: np.ndarray, v: np.ndarray) -> None:
-        """Scatter one token per sequence ([B, KV, hd]) into pool rows
-        addressed by ``last_slots`` — the vectorized decode-step write (one
-        fancy-index assignment instead of B ``write`` calls per layer)."""
+    def append(self, layer: int, rows: jax.Array, k: jax.Array,
+               v: jax.Array) -> None:
+        """Write token rows ``k``/``v`` ([..., KV, hd], one per column of
+        ``rows``) into the layer's pools on the device, in place. Only the
+        dispatch runs here; nothing waits for the device."""
         with self._host("kv.write"):
-            self.k_pools[layer][:, pids, slots] = k.swapaxes(0, 1)
-            self.v_pools[layer][:, pids, slots] = v.swapaxes(0, 1)
-        self._dirty[layer] = True
+            self.k_pools[layer], self.v_pools[layer] = _kv_append(
+                self.k_pools[layer], self.v_pools[layer], rows, k, v)
+        self.rows_written += int(rows.shape[1])
 
     # ------------------------------------------------------------ views
     def page_table(self, seq_ids: Sequence) -> Tuple[np.ndarray, np.ndarray]:
@@ -246,27 +244,17 @@ class PagedKVCache:
             pt[i, :len(p)] = p
         return pt, np.asarray(lens, np.int32)
 
-    def device_pools(self, layer: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
-        """The layer's page pools as device arrays (re-uploaded only after a
-        host-side write dirtied the layer)."""
-        if self._dirty[layer] or self._dev[layer] is None:
-            kp, vp = self.k_pools[layer], self.v_pools[layer]
-            with self._host("kv.upload"):
-                self._dev[layer] = (jnp.asarray(kp), jnp.asarray(vp))
-            self._dirty[layer] = False
-            self.upload_bytes += kp.nbytes + vp.nbytes
-        return self._dev[layer]
-
     @property
     def device_pool_bytes(self) -> int:
-        """Bytes the cached device copies of the pools hold now (outside
-        the ledger, which charges allocated pages only)."""
-        return sum(k.nbytes + v.nbytes for k, v in
-                   (d for d in self._dev if d is not None))
+        """Bytes the pools hold on the device (outside the ledger, which
+        charges allocated pages only)."""
+        return sum(k.nbytes + v.nbytes
+                   for k, v in zip(self.k_pools, self.v_pools))
 
     def counters(self) -> Dict[str, float]:
-        """The host round trip's counters and the device-pool gauge."""
-        return {"upload_bytes": self.upload_bytes,
+        """The KV path's counters and the device-pool gauge."""
+        return {"rows_written": self.rows_written,
+                "upload_bytes": self.upload_bytes,
                 "device_pool_bytes": self.device_pool_bytes,
                 "host_s": self.host_s}
 
@@ -309,8 +297,8 @@ class PagedBatchView:
     builds the view: ``seq_lens`` already counts the token being decoded, so
     each layer's new K/V lands at position ``seq_lens[i] - 1`` and the
     kernel's causal mask (`q_pos = seq_len - 1`) covers exactly the live
-    context. The (page_table, seq_lens) device arrays are uploaded once and
-    shared by all layers of the step.
+    context. The (page_table, seq_lens) device arrays and the append's rows
+    are uploaded once and shared by all layers of the step.
     """
 
     def __init__(self, kv: PagedKVCache, seq_ids: Sequence, step: int = -1):
@@ -318,10 +306,7 @@ class PagedBatchView:
         self.seq_ids = list(seq_ids)
         self.step = step        # engine step number (-1 outside one), traced
         pt, sl = kv.page_table(self.seq_ids)
-        self._host_lens = sl
-        # every layer writes the SAME (page, slot) per sequence this step —
-        # resolve the addressing once, scatter per layer
-        self._w_pids, self._w_slots = kv.last_slots(self.seq_ids)
+        self._rows = kv.last_rows(self.seq_ids)
         self.page_table = jnp.asarray(pt)
         self.seq_lens = jnp.asarray(sl)
 
@@ -329,12 +314,13 @@ class PagedBatchView:
                window: Optional[int] = None,
                softcap: Optional[float] = None):
         """Append this layer's new K/V ([B, KV, hd]) to each sequence's
-        pages, then attend q ([B, H, hd]) through the page table."""
-        k, v = self.kv.readback(k_new, v_new)
-        self.kv.write_rows(layer, self._w_pids, self._w_slots, k, v)
-        kp, vp = self.kv.device_pools(layer)
-        return ops.paged_attention(q, kp, vp, self.page_table, self.seq_lens,
-                                   scale=scale, window=window, softcap=softcap)
+        pages on the device, then attend q ([B, H, hd]) through the page
+        table."""
+        self.kv.append(layer, self._rows, k_new, v_new)
+        return ops.paged_attention(q, self.kv.k_pools[layer],
+                                   self.kv.v_pools[layer], self.page_table,
+                                   self.seq_lens, scale=scale, window=window,
+                                   softcap=softcap)
 
     def bind(self, layer: int) -> _LayerBoundView:
         return _LayerBoundView(self, layer)

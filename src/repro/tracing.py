@@ -11,8 +11,7 @@ only switch.
 Spans, by thread (each nests in the one above it):
 
   executor   ``decode_step`` (step) > ``exec`` (block) > ``exec.sync``,
-             ``kv.readback.wait``, ``kv.readback``, ``kv.write``,
-             ``kv.upload``;
+             ``kv.write`` (the dispatch of a layer's K/V append);
              ``admit`` (rid) > ``prefill`` > ``exec`` > ...;
              ``swap.wait`` and ``swap.out`` between the blocks of a pass
   loader     ``swap.read``, ``swap.unpack``, ``swap.dispatch`` (unit)

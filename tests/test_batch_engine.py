@@ -6,6 +6,7 @@ contiguous-cache run produces, while the step trace shows batch membership
 actually changing every iteration (admissions and retirements at step
 boundaries, preemption-by-recomputation under page pressure).
 """
+import contextlib
 import dataclasses
 import tempfile
 
@@ -185,3 +186,61 @@ def test_scheduler_generate_integration(setup):
         assert len(sched.completed) == 3
         assert all(h.latency_s > 0 for h in handles)
         rt.close()
+
+
+def test_decode_keeps_kv_on_the_device(setup, monkeypatch):
+    """K/V never leave the device. Past admission, every decode step
+    writes each layer's pools in place: the same device buffers before and
+    after the step (the append donates them), no pool byte uploaded, and
+    no span but ``kv.write`` in the KV path (no readback, no upload).
+    ``rows_written`` counts the prompt tokens seeded plus the tokens
+    decoded, times the layers; the tokens stay the solo run's."""
+    import repro.core.runtime as runtime_mod
+    import repro.serving.batch_engine as engine_mod
+    import repro.serving.paged_kv as paged_kv_mod
+    cfg, model, params, prompts, solo = setup
+    max_new = [4, 3]
+    want = [solo(prompts[i], max_new[i]) for i in range(2)]
+    opened = []
+
+    def recording(name, **_):
+        opened.append(name)
+        return contextlib.nullcontext()
+    for mod in (runtime_mod, engine_mod, paged_kv_mod):
+        monkeypatch.setattr(mod, "span", recording)
+    with tempfile.TemporaryDirectory() as d:
+        sm = _swapped(model, params, d)
+        kv = PagedKVCache(cfg, MemoryLedger(1 << 30), page_tokens=4,
+                          max_pages=8)
+        be = BatchDecodeEngine(sm, kv, max_batch=2)
+        reqs = [Request(i, list(prompts[i]), max_new_tokens=max_new[i])
+                for i in range(2)]
+        for r in reqs:
+            be.submit(r)
+
+        def buffers():
+            return [(k.unsafe_buffer_pointer(), v.unsafe_buffer_pointer())
+                    for k, v in zip(kv.k_pools, kv.v_pools)]
+        decoded = seeded = 0
+        while True:
+            before, rows = buffers(), kv.rows_written
+            del opened[:]
+            tr = be.step()
+            if tr is None:
+                break
+            seeded += sum(len(prompts[rid]) for rid in tr.admitted)
+            decoded += len(tr.batch)
+            assert buffers() == before
+            assert kv.upload_bytes == 0
+            assert kv.rows_written - rows == cfg.n_layers * (
+                len(tr.batch) + sum(len(prompts[rid])
+                                    for rid in tr.admitted))
+            kv_spans = [n for n in opened if n.startswith("kv.")]
+            assert set(kv_spans) <= {"kv.write"}
+            if tr.batch and not tr.admitted:
+                assert len(kv_spans) == cfg.n_layers
+        sm.close()
+    assert [list(r.output) for r in reqs] == want
+    assert decoded == sum(max_new) - len(reqs)   # the first token: prefill
+    assert kv.rows_written == cfg.n_layers * (seeded + decoded)
+    assert be.stats()["kv_rows_written"] == kv.rows_written

@@ -154,6 +154,19 @@ def test_pool_exhaustion_independent_of_ledger():
     assert kv.alloc("b", 1)
 
 
+def _np_write(pool, pages, start, rows, T):
+    """The numpy reference of a pool write: token rows [S, KV, hd] at
+    positions start .. start+S of a sequence owning ``pages``, one page's
+    run at a time."""
+    t = 0
+    while t < rows.shape[0]:
+        pos = start + t
+        slot = pos % T
+        n = min(T - slot, rows.shape[0] - t)
+        pool[:, pages[pos // T], slot:slot + n] = rows[t:t + n].swapaxes(0, 1)
+        t += n
+
+
 def test_write_page_table_roundtrip_and_sentinel():
     cfg = _cfg()
     kv = PagedKVCache(cfg, MemoryLedger(None), page_tokens=4, max_pages=8)
@@ -162,19 +175,94 @@ def test_write_page_table_roundtrip_and_sentinel():
     kv.alloc("a", 6)
     k = rng.standard_normal((6, KV, hd)).astype(np.float32)
     v = rng.standard_normal((6, KV, hd)).astype(np.float32)
-    kv.write("a", 0, 0, k, v)               # spans a page boundary
+    kv.append(0, kv.rows("a", 0, 6), jnp.asarray(k),
+              jnp.asarray(v))               # spans a page boundary
     pt, sl = kv.page_table(["a"])
     assert sl.tolist() == [6] and pt.shape == (1, 2)
     gathered = _gather(kv.k_pools[0], pt[0])[:6]
     np.testing.assert_array_equal(gathered, k)
+    np.testing.assert_array_equal(_gather(kv.v_pools[0], pt[0])[:6], v)
     # sentinel page 0 is never handed out and never written
     assert 0 not in pt[0]
-    assert not kv.k_pools[0][:, 0].any()
+    assert not np.asarray(kv.k_pools[0])[:, 0].any()
     # a second, longer sequence pads the FIRST one's table row with 0s
     kv.alloc("b", 16)
     pt2, _ = kv.page_table(["a", "b"])
     assert pt2.shape == (2, 4)
     assert (pt2[0, 2:] == 0).all()
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", ["decode", "prefill"])
+def test_device_append_matches_numpy_scatter(case, dtype):
+    """The on-device append writes exactly what a numpy scatter writes, bit
+    for bit: one row per sequence at its last position (decode), or a
+    whole prompt from position 0 across page boundaries (prefill seeding,
+    from the prefill's [1, S, KV, hd] K/V). Page 0 stays zero."""
+    cfg = dataclasses.replace(_cfg(), dtype=dtype)
+    T = 4
+    kv = PagedKVCache(cfg, MemoryLedger(None), page_tokens=T, max_pages=12)
+    KV, hd = cfg.n_kv_heads, cfg.resolved_head_dim
+    dt = jnp.dtype(dtype)
+    want_k = np.zeros(kv.k_pools[1].shape, dt)
+    want_v = np.zeros(kv.v_pools[1].shape, dt)
+    rng = np.random.default_rng(7)
+    lens = {"a": 11, "b": 3, "c": 8}
+    for sid, n in lens.items():
+        assert kv.alloc(sid, n)
+
+    def page_list(sid):
+        pt, _ = kv.page_table([sid])
+        return pt[0]
+    if case == "prefill":
+        for sid, n in lens.items():
+            k = rng.standard_normal((1, n, KV, hd)).astype(dt)
+            v = rng.standard_normal((1, n, KV, hd)).astype(dt)
+            kv.append(1, kv.rows(sid, 0, n), jnp.asarray(k), jnp.asarray(v))
+            _np_write(want_k, page_list(sid), 0, k[0], T)
+            _np_write(want_v, page_list(sid), 0, v[0], T)
+    else:
+        sids = list(lens)
+        for _ in range(6):            # crosses "a"'s and "c"'s boundaries
+            for sid in sids:
+                assert kv.extend(sid, 1)
+            k = rng.standard_normal((len(sids), KV, hd)).astype(dt)
+            v = rng.standard_normal((len(sids), KV, hd)).astype(dt)
+            kv.append(1, kv.last_rows(sids), jnp.asarray(k), jnp.asarray(v))
+            for i, sid in enumerate(sids):
+                pos = kv.seq_len(sid) - 1
+                _np_write(want_k, page_list(sid), pos, k[i:i + 1], T)
+                _np_write(want_v, page_list(sid), pos, v[i:i + 1], T)
+    got_k, got_v = np.asarray(kv.k_pools[1]), np.asarray(kv.v_pools[1])
+    assert got_k.dtype == dt
+    np.testing.assert_array_equal(got_k.view(np.uint8), want_k.view(np.uint8))
+    np.testing.assert_array_equal(got_v.view(np.uint8), want_v.view(np.uint8))
+    assert not got_k[:, 0].any() and not got_v[:, 0].any()
+    # the other layers were not touched
+    assert not np.asarray(kv.k_pools[0]).any()
+    written = sum(lens.values()) if case == "prefill" else 6 * len(lens)
+    assert kv.rows_written == written
+
+
+def test_append_donates_the_old_pools():
+    """The append reuses the pools' device buffers: the arrays handed in
+    are deleted (donated) and the new ones sit in the same memory, so no
+    pool is copied or re-uploaded."""
+    cfg = _cfg()
+    kv = PagedKVCache(cfg, MemoryLedger(None), page_tokens=4, max_pages=8)
+    KV, hd = cfg.n_kv_heads, cfg.resolved_head_dim
+    kv.alloc("a", 5)
+    old_k, old_v = kv.k_pools[0], kv.v_pools[0]
+    ptrs = (old_k.unsafe_buffer_pointer(), old_v.unsafe_buffer_pointer())
+    kv.append(0, kv.rows("a", 0, 5), jnp.ones((1, 5, KV, hd)),
+              jnp.ones((1, 5, KV, hd)))
+    assert old_k.is_deleted() and old_v.is_deleted()
+    new_k, new_v = kv.k_pools[0], kv.v_pools[0]
+    assert (new_k.unsafe_buffer_pointer(),
+            new_v.unsafe_buffer_pointer()) == ptrs
+    assert not new_k.is_deleted() and not new_v.is_deleted()
+    assert kv.upload_bytes == 0
+    assert kv.device_pool_bytes == cfg.n_layers * 2 * new_k.nbytes
 
 
 def test_rejects_non_uniform_attention():
@@ -246,7 +334,7 @@ def test_batch_view_write_position():
     kv.alloc("a", 5)
     k0 = rng.standard_normal((5, KV, hd)).astype(np.float32)
     v0 = rng.standard_normal((5, KV, hd)).astype(np.float32)
-    kv.write("a", 0, 0, k0, v0)
+    kv.append(0, kv.rows("a", 0, 5), jnp.asarray(k0), jnp.asarray(v0))
     assert kv.extend("a", 1)
     view = PagedBatchView(kv, ["a"])
     q = jnp.asarray(rng.standard_normal((1, H, hd)).astype(np.float32))

@@ -2,9 +2,9 @@
 
 The spans (``repro.tracing.span``) land in a ``jax.profiler`` trace nested
 in the span that caused them, on the thread that ran them, and nowhere
-when no profiler session is open. The counters (``PagedKVCache``'s host
-round trip, ``BatchDecodeEngine``'s admission wait) count each byte and
-each admission once, and reach the operator's ``/metrics`` surface.
+when no profiler session is open. The counters (``PagedKVCache``'s rows
+appended on the device, ``BatchDecodeEngine``'s admission wait) count each
+row and each admission once, and reach the operator's ``/metrics`` surface.
 """
 import dataclasses
 import glob
@@ -105,26 +105,21 @@ def test_decode_step_spans_nest_on_the_executor_thread(traced):
     assert len(steps) == int(be.stats()["decode_steps"])
     assert sorted(s[4]["step"] for s in steps) == sorted(
         t.step for t in be.trace if t.batch)
-    # decode_step > exec > kv.upload, and exec > exec.sync, on one thread;
-    # the decode path's readback and write run inside a block's exec, the
-    # prompt's inside its admission
-    for name in ("kv.readback.wait", "kv.readback", "kv.write", "kv.upload"):
-        for ev in by[name]:
-            assert any(_inside(ev, e) for e in by["exec"] + by["admit"]), name
-    # the wait for the K/V's programs closes before the copy opens, so
-    # the copy's span holds only the copy
-    assert len(by["kv.readback.wait"]) == len(by["kv.readback"])
-    for copy in by["kv.readback"]:
-        assert any(w[0] == copy[0] and w[3] <= copy[2]
-                   for w in by["kv.readback.wait"])
-    for ev in by["kv.upload"]:
-        assert any(_inside(ev, e) for e in by["exec"])
-        assert len([s for s in steps if _inside(ev, s)]) == 1
+    # decode_step > exec > kv.write, and exec > exec.sync, on one thread;
+    # the decode path's appends run inside a block's exec, the prompt's
+    # inside its admission. K/V stay on the device: no readback, no wait
+    # for the K/V's programs, no pool upload
+    for name in ("kv.readback.wait", "kv.readback", "kv.upload"):
+        assert name not in by, name
+    for ev in by["kv.write"]:
+        assert any(_inside(ev, e) for e in by["exec"] + by["admit"])
+        assert len([s for s in steps + by["admit"] if _inside(ev, s)]) == 1
     for ev in by["exec.sync"]:
         assert any(_inside(ev, e) for e in by["exec"])
     assert all(any(_inside(e, s) for s in steps + by["prefill"])
                for e in by["exec"])
-    assert len(by["kv.upload"]) == len(steps) * be.sm.cfg.n_layers
+    appends = [e for e in by["kv.write"] if any(_inside(e, s) for s in steps)]
+    assert len(appends) == len(steps) * be.sm.cfg.n_layers
 
 
 def test_admission_spans_carry_the_request(traced):
@@ -133,10 +128,10 @@ def test_admission_spans_carry_the_request(traced):
     assert sorted(e[4]["rid"] for e in admits) == [0, 1, 2]
     prefills = [e for e in events if e[1] == "prefill"]
     assert all(any(_inside(p, a) for a in admits) for p in prefills)
-    # the prompt's K/V readback and seeding happen inside the admission
-    seeded = [e for e in events if e[1] in ("kv.readback", "kv.write")
-              and any(_inside(e, a) for a in admits)]
-    assert seeded
+    # the prompt's K/V are seeded inside the admission, one append a layer
+    for a in admits:
+        seeded = [e for e in events if e[1] == "kv.write" and _inside(e, a)]
+        assert len(seeded) == be.sm.cfg.n_layers
 
 
 def test_loader_stages_run_on_their_own_thread(traced):
@@ -166,11 +161,11 @@ def test_no_span_recorded_without_a_profiler_session(setup, tmp_path):
 
 
 def test_kv_upload_bytes_per_decode_step(setup):
-    """Every decode step re-uploads each layer's K and V pool once: the
-    counter grows by exactly n_layers x 2 x pool bytes, whatever the
-    batch. The engine's ``stats()`` pairs the counters with the steps
-    that ended, so a reading taken inside a step still divides to the
-    exact layout."""
+    """No decode step uploads a pool byte: the pools live on the device
+    and take each step's rows in place. What grows is ``rows_written``,
+    by n_layers x the batch a step. The engine's ``stats()`` pairs the
+    counters with the steps that ended, so a reading taken inside a step
+    still divides to the exact count."""
     cfg, model, params, prompts = setup
     with tempfile.TemporaryDirectory() as d:
         sm, be = _engine(model, params, d)
@@ -183,32 +178,37 @@ def test_kv_upload_bytes_per_decode_step(setup):
 
         def reading_inside(batch, view):
             out = step(batch, view)
-            inside.append(be.stats())          # the step's uploads are done
+            inside.append(be.stats())          # the step's appends are done
             return out
         sm.decode_step_paged = reading_inside
         seen = 0
         while True:
-            up = kv.upload_bytes
+            rows = kv.rows_written
             before = be.stats()
             tr = be.step()
             if tr is None:
                 break
+            assert kv.upload_bytes == 0
+            seeded = cfg.n_layers * sum(len(prompts[r]) for r in tr.admitted)
             if not tr.batch:
-                assert kv.upload_bytes == up
+                assert kv.rows_written - rows == seeded
                 continue
             seen += 1
-            assert kv.upload_bytes - up == cfg.n_layers * 2 * pool
-            assert inside[-1]["kv_upload_bytes"] == before["kv_upload_bytes"]
+            step_rows = cfg.n_layers * len(tr.batch)
+            assert kv.rows_written - rows == seeded + step_rows
+            assert inside[-1]["kv_rows_written"] == before["kv_rows_written"]
             assert inside[-1]["decode_steps"] == before["decode_steps"]
             after = be.stats()
-            assert after["kv_upload_bytes"] - before["kv_upload_bytes"] \
-                == cfg.n_layers * 2 * pool
+            assert after["kv_rows_written"] - before["kv_rows_written"] \
+                == seeded + step_rows
+            assert after["kv_upload_bytes"] == 0
         sm.close()
     assert seen >= 4
     assert kv.device_pool_bytes == cfg.n_layers * 2 * pool
     assert kv.host_s > 0
     st = be.stats()
-    assert st["kv_upload_bytes"] == kv.upload_bytes
+    assert st["kv_upload_bytes"] == kv.upload_bytes == 0
+    assert st["kv_rows_written"] == kv.rows_written
     assert st["kv_device_pool_bytes"] == cfg.n_layers * 2 * pool
 
 
@@ -247,12 +247,14 @@ def test_registry_exports_the_new_counters(traced):
     _, be, samples = traced
     got = {name: v for name, labels, v in samples
            if labels == {"model": "m"}}
-    assert got["swapnet_kv_upload_bytes_total"] == be.kv.upload_bytes > 0
+    assert got["swapnet_kv_rows_written_total"] == be.kv.rows_written > 0
+    assert got["swapnet_kv_upload_bytes_total"] == be.kv.upload_bytes == 0
     assert got["swapnet_kv_device_pool_bytes"] == be.kv.device_pool_bytes > 0
     assert got["swapnet_admit_wait_seconds_total"] == be.admit_wait_s
     assert got["swapnet_admitted_total"] == be.admitted == 3
     families = {name for name, _, _ in METRIC_FAMILIES}
-    assert {"swapnet_kv_upload_bytes_total", "swapnet_kv_device_pool_bytes",
+    assert {"swapnet_kv_rows_written_total",
+            "swapnet_kv_upload_bytes_total", "swapnet_kv_device_pool_bytes",
             "swapnet_admit_wait_seconds_total",
             "swapnet_admitted_total"} <= families
 
