@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
-"""The control, on the chip: the cell run with the plain reference,
-computed with float8 matmul inputs (``reference.py``), in the program's
-place.
+"""The control, on the chip: the cell run with its family's plain
+reference, computed with float8 matmul inputs (``reference.py``), in the
+program's place.
 
     python3 bench/control.py --workload <name> --seeds 1,2,3 --seconds <s>
 

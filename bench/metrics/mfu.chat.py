@@ -1,5 +1,6 @@
 """Model step: FLOPs of every prefill and decode token processed in the
-window (bench/counts.py) over the window times the chip's bf16 peak (%)."""
+window, as the configuration's family counts them, over the window times
+the chip's bf16 peak (%)."""
 import readers
 
 

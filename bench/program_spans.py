@@ -6,15 +6,14 @@ about the device's idle gaps.
 
 reads the newest ``*.xplane.pb`` under ``trace_dir`` (a run of
 ``bench/run.py --trace 1 --trace-dir <trace_dir>``) and prints one JSON
-object for the window: the ``--seconds`` (the result line's
-``device.window_s``) that end where the ``bench.window`` span ends, or the
-whole span without it. The span opens when its annotation is made, before
-the load's ramp, and closes with the window, so its end is the window's.
+object for the window: the ``bench.window`` span, or the ``--seconds``
+(the result line's ``device.window_s``) that end where it ends. The span
+opens and closes with the window.
 
 * ``idle_gaps``: the ten longest gaps in which the device ran nothing,
   each named by the chain of ``swapnet.*`` spans open at its midpoint on
   the thread that holds the covering ``swapnet.decode_step``,
-  ``swapnet.admit`` or ``swapnet.prefill`` (``decode_step>exec>kv.upload``);
+  ``swapnet.admit`` or ``swapnet.prefill`` (``decode_step>exec>kv.write``);
   a gap no program span covers keeps the harness span around it, or
   ``outside_layers``;
 * ``executor_s``: seconds of the window per innermost program span on
@@ -23,9 +22,10 @@ the load's ramp, and closes with the window, so its end is the window's.
   ``executor_s_loader_busy``: the part of each during which a loader
   thread was inside a ``swapnet.swap.*`` span;
 * ``loader_s``: seconds per innermost span on the other threads;
-* ``kv_host_share``: the union of the ``swapnet.kv.readback``,
-  ``swapnet.kv.write`` and ``swapnet.kv.upload`` spans over the window, in
-  %, the trace's reading of what ``kv_host_share.chat`` counts.
+* ``kv_host_share``: the union of the ``swapnet.kv.write`` spans (the
+  dispatch of each layer's K/V append into the pools on the device) over
+  the window, in %, the trace's reading of what ``kv_host_share.chat``
+  counts.
 
 The program's spans are ``repro.tracing.span`` annotations: events whose
 name starts with ``swapnet.`` on the host planes, one line per thread,
@@ -50,7 +50,7 @@ import trace as tr                  # noqa: E402
 
 PREFIX = "swapnet."
 ROOTS = ("decode_step", "admit", "prefill")
-KV_HOST = ("kv.readback", "kv.write", "kv.upload")
+KV_HOST = ("kv.write",)
 
 
 @dataclass(frozen=True)

@@ -49,12 +49,13 @@ def loader_gbps(r) -> Optional[float]:
 
 
 def model_flops(r) -> float:
-    """FLOPs of every prefill and decode token processed in the window."""
+    """FLOPs of every prefill and decode token processed in the window, as
+    the configuration's family counts them."""
     total = 0.0
     for _, _, _, n in r.layer_spans("bench.prefill"):
-        total += counts.prefill_flops(r.d, int(n))
+        total += r.family.prefill_flops(r.d, int(n))
     for _, _, _, lens in r.layer_spans("bench.decode_step"):
-        total += sum(counts.decode_flops(r.d, int(x)) for x in lens)
+        total += sum(r.family.decode_flops(r.d, int(x)) for x in lens)
     return total
 
 
@@ -75,14 +76,15 @@ def device_idle(r) -> Optional[float]:
 
 
 def kernel_roofline(r, program: str) -> Optional[float]:
-    """Share, in %, of the roofline the paged-attention kernel reached over
-    the decode steps of the window: the least time its calls could take
-    at the chip's peaks over the device time of the programs named
-    ``program``, inside those steps."""
-    if r.trace is None:
-        return None
+    """Share, in %, of the roofline the device programs named ``program``
+    reached over the decode steps of the window: the least time their calls
+    could take at the chip's peaks, by the family's ``kernel_cost``, over
+    their device time inside those steps. None where the family has no
+    cost for ``program``."""
     steps = r.layer_spans("bench.decode_step")
-    if not steps:
+    costs = [r.family.kernel_cost(r.d, program, lens)
+             for _, _, _, lens in steps]
+    if r.trace is None or not steps or None in costs:
         return None
     within = [(s + r.offset, e + r.offset) for _, s, e, _ in steps]
     secs = tr.program_seconds(r.trace, r.t_open + r.offset,
@@ -90,9 +92,7 @@ def kernel_roofline(r, program: str) -> Optional[float]:
     kernel = sum(v for k, v in secs.items() if program in k)
     if kernel <= 0:
         return None
-    least = sum(r.d["L"] * counts.least_seconds(
-        *counts.paged_attention_cost(r.d, lens), r.peak)
-        for _, _, _, lens in steps)
+    least = sum(counts.least_seconds(*c, r.peak) for c in costs)
     return 100.0 * least / kernel
 
 

@@ -3,15 +3,16 @@
 
     JAX_PLATFORMS=cpu python3 bench/rehearse_compile.py [config]
 
-(default ``h2o-danube-3-4b``). No chip is needed: the TPU compiler builds
-each program for a v5e that is described, not attached, and refuses what
-the chip's compiler would refuse (a kernel tile it cannot lower, more
-memory than the chip has). Compiled at the configuration's published
-widths, at the shapes its chat cell sends:
+(default ``h2o-danube-3-4b``), a configuration of the ``dense_gqa``
+family, with the file's ``program`` cut applied. No chip is needed: the
+TPU compiler builds each program for a v5e that is described, not
+attached, and refuses what the chip's compiler would refuse (a kernel
+tile it cannot lower, more memory than the chip has). Compiled at the
+configuration's published widths, at the shapes its chat cell sends:
 
 * the swapped prefill layer (``apply_layer_jit``) at the shortest and the
   longest prompt of the mix;
-* the paged decode layer's two programs around the host-side page append
+* the paged decode layer's two programs around the page append
   (``_paged_qkv``, ``_paged_out``) and the head, at batch 1 and 8;
 * the ``paged_attention`` kernel, forced to its Mosaic lowering, at batch
   8 and the mix's most pages, over the KV pool the budget sizes.
@@ -36,12 +37,12 @@ import jax.numpy as jnp                             # noqa: E402
 from jax.experimental import topologies             # noqa: E402
 from jax.sharding import SingleDeviceSharding       # noqa: E402
 
+import family                                       # noqa: E402
 import traffic                                      # noqa: E402
-from reference import dims, param_shapes            # noqa: E402
 
 
 def main(name: str = "h2o-danube-3-4b") -> int:
-    from repro.configs import get_arch
+    from serving import program_config
     from repro.core.runtime import head_logits
     from repro.kernels.paged_attention import paged_attention
     from repro.models import attention as attn_mod
@@ -49,8 +50,9 @@ def main(name: str = "h2o-danube-3-4b") -> int:
                                           apply_layer_jit)
     cfg = json.loads((BENCH / "configs" / f"{name}.json").read_text())
     mix = json.loads((BENCH / "traffic" / "chat.json").read_text())
-    mc = get_arch(cfg["arch"])
-    d = dims(cfg["model"])
+    fam = family.of(cfg, BENCH.parent)
+    mc = program_config(cfg, fam)
+    d = fam.dims(cfg["model"])
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
     chip = SingleDeviceSharding(topo.devices[0])
@@ -60,7 +62,7 @@ def main(name: str = "h2o-danube-3-4b") -> int:
         return jax.ShapeDtypeStruct(tuple(shape), dt, sharding=chip)
 
     layer = {}
-    for path, (shape, _, _) in param_shapes(d).items():
+    for path, (shape, _, _) in fam.param_shapes(d).items():
         if path.startswith("segments/0/"):
             node = layer
             parts = path.split("/")[2:]
@@ -71,7 +73,7 @@ def main(name: str = "h2o-danube-3-4b") -> int:
     local = mc.is_local_layer(0)
     T = cfg["deployment"]["runtime"]["page_tokens"]
     stored = sum(int(jnp.prod(jnp.asarray(s))) * 2
-                 for s, _, _ in param_shapes(d).values())
+                 for s, _, _ in fam.param_shapes(d).values())
     stored += D * V * 2 if d["tied"] else 0
     budget = stored / cfg["budget_ratio"]
     pages = int(budget * cfg["deployment"]["runtime"]["kv_frac"]

@@ -5,25 +5,26 @@
 
 from the root of a checkout, on a machine with the chips the cell asks
 for. Everything is found by name from ``BENCHMARK.json``: the cell's
-configuration file (``bench/configs/<config>.json``), its traffic mix
+configuration file (``bench/configs/<config>.json``), the model family it
+names (``bench/families/<family>.py``, ``family.py``), its traffic mix
 (``bench/traffic/<traffic>.json``), and one reader per metric
 (``bench/metrics/<metric>.py``). With ``--trace 0`` the line carries the
 cell's end-to-end metrics; with ``--trace 1`` a profiler trace of the
 window gives its per-layer metrics and a breakdown.
 
-A run: weights from the seed, the program's serving stack built on them
-(``serving.System``), every shape of the mix warmed, the load started
-and, once it is steady, ``--seconds`` of window. After the window the
-open requests are closed, the program's state freed, and a sample of
-the finished requests compared with the
-plain reference over weights made again from the seed
-(``reference.py``). The last lines on standard error, and the ``checks``
-key that ends the result line, give each number compared beside its
-limit. ``memory_peak_bytes`` is the device's bytes in use at their
-highest while the window was open, sampled every 20 ms; the process's
-peak, which the harness's own weights set before the program stores
-them, is ``process_peak_bytes`` beside it. Without a TPU, or on a device
-missing from ``bench/peaks.json``, it exits 2 and prints no result.
+A run: the family's weights from the seed, the program's serving stack
+built on them (``serving.System``), every shape of the mix warmed, the
+load started and, once it is steady, ``--seconds`` of window. After the
+window the open requests are closed, the program's state freed, and a
+sample of the finished requests compared with the family's plain
+reference over weights made again from the seed (``reference.py``). The
+last lines on standard error, and the ``checks`` key that ends the result
+line, give each number compared beside its limit. ``memory_peak_bytes``
+is the device's bytes in use at their highest while the window was open,
+sampled every 20 ms; the process's peak, which the harness's own weights
+set before the program stores them, is ``process_peak_bytes`` beside it.
+Without a TPU, or on a device missing from ``bench/peaks.json``, it exits
+2 and prints no result.
 """
 from __future__ import annotations
 
@@ -42,12 +43,15 @@ import threading                                       # noqa: E402
 import traceback                                       # noqa: E402
 from dataclasses import dataclass                      # noqa: E402
 from pathlib import Path                               # noqa: E402
+from types import ModuleType                           # noqa: E402
 from typing import Callable, Dict, List, Optional      # noqa: E402
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
 CACHE_DIR = BENCH / ".jax_cache"
 sys.path.insert(0, str(BENCH))
+
+import family as families                              # noqa: E402
 
 
 # ------------------------------------------------------------ the cell
@@ -56,6 +60,7 @@ class Cell:
     name: str
     chips: int
     cfg: dict
+    family: ModuleType              # bench/families/<name>.py
     mix: dict
     metrics: List[dict]             # end-to-end, then per-layer
     root: Path = ROOT
@@ -72,6 +77,7 @@ def load_cell(workload: str, root: Path = ROOT) -> Cell:
     w = cells[workload]
     conf = {c["name"]: c for c in bench["configs"]}[w["config"]]
     cfg = json.loads((root / conf["file"]).read_text())
+    fam = families.of(cfg, root)
     mix = json.loads((root / "bench" / "traffic" /
                       f"{w['traffic']}.json").read_text())
 
@@ -83,7 +89,8 @@ def load_cell(workload: str, root: Path = ROOT) -> Cell:
     layer = [dict(m, per_layer=True) for m in bench["per_layer"]
              if (workload in m["workloads"] if "workloads" in m
                  else m["moves"] in names)]
-    return Cell(workload, int(w["chips"]), cfg, mix, e2e + layer, root)
+    return Cell(workload, int(w["chips"]), cfg, fam, mix, e2e + layer,
+                root)
 
 
 def reader(name: str, root: Path = ROOT) -> Callable:
@@ -100,8 +107,9 @@ def reader(name: str, root: Path = ROOT) -> Callable:
 class Readings:
     """What a metric reader may read: the window on ``time.perf_counter``,
     every request sent, the harness's spans around the layers, the swap
-    engine's timeline, counters at the window's open and close, and with
-    ``--trace 1`` the reduced trace and its clock's offset."""
+    engine's timeline, counters at the window's open and close, the
+    configuration's family and its sizes, and with ``--trace 1`` the
+    reduced trace and its clock's offset."""
     t_open: float
     t_close: float
     setup_s: float
@@ -112,6 +120,7 @@ class Readings:
     close: dict
     d: dict
     peak: dict
+    family: ModuleType
     trace: object = None
     offset: float = 0.0             # trace clock - perf_counter
 
@@ -181,7 +190,7 @@ def check_generate(cell: Cell, w, params, seed: int, control: bool) -> dict:
                           lambda s: len(s.spec.prompt) + len(s.req.output))
     if not pick:
         return {"served_gap": float("inf"), "tokens": 0}
-    return served_gap(cell.cfg["model"], params,
+    return served_gap(cell.family, cell.cfg["model"], params,
                       [s.spec.prompt for s in pick],
                       [list(s.req.output) for s in pick],
                       traffic.max_context(cell.mix), control=control)
@@ -196,7 +205,7 @@ def check_prefill(cell: Cell, w, params, seed: int, control: bool) -> dict:
                           lambda s: len(s.spec.prompt))
     if not pick:
         return {"logit_err": float("inf"), "tokens": 0}
-    return logit_err(cell.cfg["model"], params,
+    return logit_err(cell.family, cell.cfg["model"], params,
                      [s.spec.prompt for s in pick],
                      [np.asarray(s.answer, np.float32)[0, -1] for s in pick],
                      control=control)
@@ -210,9 +219,10 @@ def resident_prefill_ms(cell: Cell, params, w) -> Dict[int, float]:
     each prompt length the window sent (the paper's comparison)."""
     import jax
     import jax.numpy as jnp
-    from repro.configs import get_arch
+    import serving
     from repro.models.transformer import Model
-    fn = jax.jit(Model(get_arch(cell.cfg["arch"])).prefill)
+    mc = serving.program_config(cell.cfg, cell.family)
+    fn = jax.jit(Model(mc).prefill)
     out = {}
     for n in sorted({len(s.spec.prompt) for s in w.sent}):
         batch = {"tokens": jnp.ones((1, n), jnp.int32)}
@@ -254,15 +264,16 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     import jax
     import serving
     import trace as tr
-    from reference import dims, make_params
+    fam = cell.family
 
     counter = serving.CompileCounter()
     t_build = time.perf_counter()
-    params = make_params(cell.cfg["model"], seed)
+    params = fam.make_params(cell.cfg["model"], seed)
     jax.block_until_ready(params)
     log(f"[bench] weights made in {time.perf_counter() - t_build:.1f}s")
     with tempfile.TemporaryDirectory(prefix="bench-store-") as workdir:
-        system = serving.System(cell.cfg, params, workdir, wrap=hooks or {})
+        system = serving.System(cell.cfg, fam, params, workdir,
+                                wrap=hooks or {})
         del params
         ratio = system.stored_in_store / system.budget
         log(f"[bench] {cell.cfg['arch']}: stored units "
@@ -285,16 +296,18 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
             opts = jax.profiler.ProfileOptions()
             opts.python_tracer_level = 0    # host spans, not every call
             jax.profiler.start_trace(tdir, profiler_options=opts)
-        ann = jax.profiler.TraceAnnotation("bench.window")
         memory = MemorySampler(jax.devices()[0])
+        window = {}
 
         def on_open():
+            # the span starts when its annotation is made: here, at t_open
+            window["span"] = jax.profiler.TraceAnnotation("bench.window")
+            window["span"].__enter__()
             counter.on = True
             memory.start()
-            ann.__enter__()
 
         def on_close():
-            ann.__exit__(None, None, None)
+            window["span"].__exit__(None, None, None)
             memory.stop()
             counter.on = False
 
@@ -327,8 +340,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
         log(f"[bench] generator lateness: max {max(w.late_s) * 1e3:.1f} ms")
 
     r = Readings(w.t_open, w.t_close, setup_s, w.sent, spans, timeline,
-                 w.counters_open, w.counters_close, dims(cell.cfg["model"]),
-                 peak)
+                 w.counters_open, w.counters_close,
+                 fam.dims(cell.cfg["model"]), peak, fam)
     if trace:
         r.trace = tr.load(tdir)
         win = r.trace.spans("bench.window")
@@ -347,7 +360,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
         result["breakdown"] = breakdown(r)
     log(f"[bench] samples: " + json.dumps(sample_counts(r)))
 
-    params = make_params(cell.cfg["model"], seed)
+    params = fam.make_params(cell.cfg["model"], seed)
     if trace and cell.mix["requests"] == "prefill":
         log(f"[bench] resident jitted prefill (ms by prompt length): "
             f"{json.dumps(resident_prefill_ms(cell, params, w))}")

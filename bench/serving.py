@@ -30,18 +30,8 @@ import jax                                  # noqa: E402
 import jax.numpy as jnp                     # noqa: E402
 
 import traffic                              # noqa: E402
-from reference import dims, stored_bytes    # noqa: E402
-
-# model-block keys checked against the program's ModelConfig field
-MODEL_FIELDS = {"num_hidden_layers": "n_layers", "hidden_size": "d_model",
-                "num_attention_heads": "n_heads",
-                "num_key_value_heads": "n_kv_heads",
-                "intermediate_size": "d_ff", "vocab_size": "vocab_size",
-                "head_dim": "resolved_head_dim", "rope_theta": "rope_theta",
-                "rms_norm_eps": "norm_eps",
-                "tie_word_embeddings": "tie_embeddings",
-                "attention_bias": "attn_bias",
-                "sliding_window": "sliding_window"}
+import family                               # noqa: E402
+from reference import stored_bytes          # noqa: E402
 
 
 # ------------------------------------------------------------ compiles
@@ -100,13 +90,19 @@ class TimedTokens(list):
 
 
 # ------------------------------------------------------------ system
-def check_model(model: dict, mc) -> None:
-    """The configuration file states the model as the program runs it."""
-    for key, attr in MODEL_FIELDS.items():
+def program_config(cfg: dict, fam):
+    """The program's registered configuration of ``cfg["arch"]`` with the
+    file's ``program`` cut applied (``family.py``)."""
+    from repro.configs import get_arch
+    return family.replace(get_arch(cfg["arch"]), family.cut(cfg, fam))
+
+
+def check_model(model: dict, mc, fam) -> None:
+    """The configuration file states the model as the program runs it, key
+    by key of the family's ``MODEL_FIELDS``."""
+    for key, attr in fam.MODEL_FIELDS.items():
         want = model.get(key)
-        got = getattr(mc, attr)
-        if key == "sliding_window" and mc.layer_pattern != "swa":
-            got = None
+        got = family.program_value(mc, attr)
         same = (got == want if not isinstance(want, float)
                 else abs(float(got) - want) <= 1e-12 * abs(want))
         if not same:
@@ -117,24 +113,24 @@ def check_model(model: dict, mc) -> None:
 @dataclass
 class System:
     cfg: dict
+    family: Any                 # the configuration's family module
     params: Any
     workdir: str
     wrap: Dict[str, Callable] = field(default_factory=dict)
 
     def __post_init__(self):
         from repro.config import resolve_config
-        from repro.configs import get_arch
         from repro.core.multi_model import MultiModelRuntime
         from repro.core.serving_scheduler import ServingScheduler
         from repro.models.transformer import Model
         cfg = self.cfg
         self.arch = cfg["arch"]
-        mc = get_arch(self.arch)
-        check_model(cfg["model"], mc)
+        mc = program_config(cfg, self.family)
+        check_model(cfg["model"], mc, self.family)
         if mc.dtype != cfg["dtype"]:
             raise ValueError(f"{self.arch}: dtype {mc.dtype} != {cfg['dtype']}")
         self.model_cfg = mc
-        self.d = dims(cfg["model"])
+        self.d = self.family.dims(cfg["model"])
         dep = cfg["deployment"]
         self.stored = stored_bytes(self.params, self.d["tied"])
         self.budget = int(self.stored / cfg["budget_ratio"])
@@ -224,7 +220,9 @@ def warm(system: System, mix: dict) -> dict:
 
 def _warm_decode(system: System, mix: dict) -> int:
     """One real decode step for every batch size, then the kernel for
-    every (batch, pages) pair the mix's contexts reach."""
+    every (batch, pages) pair the mix's contexts reach: the first
+    ``attend`` call of the step, replayed with its own arguments, whatever
+    the hook's signature."""
     from repro.serving.paged_kv import PagedBatchView
     kv, sm = system.be.kv, system.sm
     T = kv.page_tokens
@@ -243,10 +241,10 @@ def _warm_decode(system: System, mix: dict) -> int:
         captured = {}
         attend = view.attend
 
-        def capture(layer, q, k, v, _attend=attend, _c=captured, **kw):
+        def capture(layer, *args, _attend=attend, _c=captured, **kw):
             if not _c:
-                _c.update(q=q, k=k, v=v, kw=kw)
-            return _attend(layer, q, k, v, **kw)
+                _c.update(layer=layer, args=args, kw=kw)
+            return _attend(layer, *args, **kw)
         view.attend = capture
         pos = np.asarray([kv.seq_len(r) - 1 for r in rids], np.int32)
         batch = {"token": jnp.asarray([[1] for _ in rids], jnp.int32),
@@ -261,7 +259,7 @@ def _warm_decode(system: System, mix: dict) -> int:
             for r, x in zip(rids, lens):
                 assert kv.alloc(r, x)
             v = PagedBatchView(kv, rids)
-            out = v.attend(0, captured["q"], captured["k"], captured["v"],
+            out = v.attend(captured["layer"], *captured["args"],
                            **captured["kw"])
             jax.block_until_ready(out)
             n += 1

@@ -1,16 +1,16 @@
-"""bench/counts.py against FLOPs and bytes worked out by hand."""
+"""The counts of the qwen configuration's family, as the harness loads it
+(``bench/families/dense_gqa.py``), and ``bench/counts.py``'s roofline,
+against FLOPs and bytes worked out by hand."""
 from __future__ import annotations
-
-import json
 
 import pytest
 
-import tiny  # noqa: F401  (puts bench/ on the path)
-import counts
-from reference import dims
+import tiny  # puts bench/ on the path
+from counts import least_seconds
 
-QWEN = dims(json.loads((tiny.BENCH / "configs" / "qwen2.5-3b.json")
-                       .read_text())["model"])
+_QWEN = tiny.run.load_cell("qwen2.5-3b.chat")
+counts = _QWEN.family            # the family the qwen file resolves to
+QWEN = counts.dims(_QWEN.cfg["model"])
 PEAK = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
 
 
@@ -46,5 +46,5 @@ def test_paged_attention_call():
     # q and out once per head per sequence, 2 bytes each
     assert flops == 4 * 16 * 128 * 30
     assert nbytes == 2 * (2 * 30 * 2 * 128) + 2 * 2 * (2 * 16 * 128)
-    assert counts.least_seconds(flops, nbytes, PEAK) == pytest.approx(
+    assert least_seconds(flops, nbytes, PEAK) == pytest.approx(
         nbytes / 819e9)

@@ -34,7 +34,7 @@ def test_traced_run_reports_the_program_metrics(traced):
     for name in NEW:
         assert name in res["metrics"], name
     m = {k: v["value"] for k, v in res["metrics"].items()}
-    assert m["kv_upload_mb.chat"] > 0
+    assert m["kv_upload_mb.chat"] == 0     # the pools live on the device
     assert 0 < m["kv_host_share.chat"] < 100
     assert m["admit_wait_ms.chat"] >= 0
 
@@ -43,10 +43,9 @@ def test_idle_gaps_carry_program_spans(traced, capsys):
     res, d = traced
     trace, spans = ps.load(d)
     seconds = res["device_busy"]["window_s"]
-    # the bench.window span opens when its annotation is made, before the
-    # load's ramp: the window is the ``seconds`` that end where it ends
+    # the bench.window span opens and closes with the window
     (lo, hi), = trace.spans("bench.window")
-    assert hi - lo > seconds
+    assert hi - lo == pytest.approx(seconds, abs=0.2)
     assert ps.main([d, "--seconds", str(seconds)]) == 0
     out = json.loads(capsys.readouterr().out)
     assert out["window_s"] == pytest.approx(seconds)
@@ -55,8 +54,9 @@ def test_idle_gaps_carry_program_spans(traced, capsys):
     assert out["loader_s"].keys() <= {"swap.read", "swap.unpack",
                                       "swap.dispatch"}
     names = {s.name for s in spans}
-    assert {"decode_step", "admit", "exec", "exec.sync", "kv.readback.wait",
-            "kv.readback", "kv.upload", "swap.read", "swap.dispatch"} <= names
+    assert {"decode_step", "admit", "exec", "exec.sync", "kv.write",
+            "swap.read", "swap.dispatch"} <= names
+    assert not names & {"kv.readback.wait", "kv.readback", "kv.upload"}
     labels = [lab for lab, _ in out["idle_gaps"]]
     assert any(lab.split(">")[0] in ps.ROOTS for lab in labels), labels
     assert 0 < out["kv_host_share"] < 100

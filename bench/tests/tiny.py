@@ -1,6 +1,9 @@
 """A cell of the benchmark cut to a size the CPU runs in seconds: the
-program's own ``.reduced()`` architecture, the mix's shape with shorter
-prompts and outputs, and a budget the tiny store fits."""
+program's own ``.reduced()`` architecture (with the file's ``program`` cut
+on top), the mix's shape with shorter prompts and outputs, and a budget
+the tiny store fits. Of the configuration's ``model`` block only the keys
+that ``.reduced()`` changes are restated; the rest stay as the file states
+them, so ``check_model`` still holds them to the program."""
 from __future__ import annotations
 
 import json
@@ -36,7 +39,6 @@ def reduce_archs(monkeypatch) -> None:
 def cell(kind: str) -> "run.Cell":
     """``poisson`` is the chat mix sent on an open-loop schedule;
     ``prefill`` the ``prefill_long`` mix on the chat cell's model."""
-    import repro.configs as rc
     c = run.load_cell(WORKLOADS.get(kind, WORKLOADS["chat"]))
     if kind == "prefill":
         c.name = "qwen2.5-3b.prefill_long"
@@ -47,12 +49,23 @@ def cell(kind: str) -> "run.Cell":
     if kind == "poisson":
         c.mix.update(arrivals={"process": "poisson", "rate": 20.0},
                      ramp_tokens=0)
-    mc = rc.get_arch(c.cfg["arch"])
-    c.cfg["model"].update(
-        num_hidden_layers=mc.n_layers, hidden_size=mc.d_model,
-        num_attention_heads=mc.n_heads, num_key_value_heads=mc.n_kv_heads,
-        head_dim=mc.resolved_head_dim, intermediate_size=mc.d_ff,
-        vocab_size=mc.vocab_size, sliding_window=mc.sliding_window)
+    return shrink(c)
+
+
+def shrink(c: "run.Cell") -> "run.Cell":
+    """The cell at the size of the program's reduced architecture (under
+    ``reduce_archs``), with the tiny mix, budget and limits. A ``model``
+    key is restated where the reduced program's value differs from the
+    registered one's, each with the file's cut applied."""
+    import family
+    import serving
+    from repro.configs import ARCHS
+    mc = serving.program_config(c.cfg, c.family)
+    full = family.replace(ARCHS[c.cfg["arch"]], family.cut(c.cfg, c.family))
+    for key, attr in c.family.MODEL_FIELDS.items():
+        got = family.program_value(mc, attr)
+        if got != family.program_value(full, attr):
+            c.cfg["model"][key] = got
     c.cfg["budget_ratio"] = 1.0
     c.cfg["limits"] = LIMITS
     if c.mix["requests"] == "generate":
