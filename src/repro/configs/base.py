@@ -29,7 +29,25 @@ class MoEConfig:
     d_shared: int = 0           # FFN hidden size of the (merged) shared expert
     aux_loss_weight: float = 0.01
     router_dtype: str = "float32"
-    capacity_factor: float = 1.25   # per-expert slot headroom (tokens beyond drop)
+    capacity_factor: float = 1.25   # training dispatch only: per-expert slot
+                                    # headroom (tokens beyond drop); serving
+                                    # routes drop-free
+    norm_topk_prob: bool = True     # renormalise the top-k gate weights to
+                                    # sum to 1 (DeepSeek-V2-Lite: False)
+
+
+@dataclass(frozen=True)
+class YaRNConfig:
+    """YaRN rope scaling in DeepSeek-V2's published form (its config's
+    ``rope_scaling``): frequencies interpolated by ``factor`` below a ramp
+    set by ``beta_fast``/``beta_slow`` rotations of the original context,
+    and an attention-softmax scale from ``mscale_all_dim``."""
+    factor: float = 40.0
+    original_max_position_embeddings: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 0.707
+    mscale_all_dim: float = 0.707
 
 
 @dataclass(frozen=True)
@@ -57,6 +75,7 @@ class ModelConfig:
     attn_bias: bool = False                 # QKV bias (qwen2)
     rope_type: str = "rope"                 # rope | mrope | none
     rope_theta: float = 10000.0
+    rope_scaling: Optional[YaRNConfig] = None
     mrope_sections: Tuple[int, int, int] = (16, 24, 24)  # t/h/w split of head_dim/2
     sliding_window: Optional[int] = None    # SWA window (danube, gemma2 local)
     attn_chunk: Optional[int] = None        # llama4 iRoPE: block-local attention
@@ -68,6 +87,9 @@ class ModelConfig:
     mla: Optional[MLAConfig] = None
     # --- mixture of experts -----------------------------------------------
     moe: Optional[MoEConfig] = None
+    first_k_dense: int = 0       # leading layers with a dense FFN of d_ff
+                                 # before the routed layers (DeepSeek's
+                                 # first_k_dense_replace)
     # --- state space / linear attention ------------------------------------
     ssm: Optional[SSMConfig] = None
     hybrid_attn_every: int = 0   # zamba2: one shared attn block every k layers
@@ -112,7 +134,7 @@ class ModelConfig:
             elif self.hybrid_attn_every > 0:
                 # zamba2: shared attn block replaces every k-th position
                 kinds.append("shared_attn" if (i % self.hybrid_attn_every) == (self.hybrid_attn_every - 1) else self.ssm.kind)
-            elif self.moe is not None:
+            elif self.moe is not None and i >= self.first_k_dense:
                 kinds.append("moe")
             else:
                 kinds.append("dense")
@@ -231,6 +253,8 @@ class ModelConfig:
         if self.moe is not None:
             kw["moe"] = replace(self.moe, n_routed=4, top_k=min(2, self.moe.top_k),
                                 d_expert=128, d_shared=128 if self.moe.n_shared else 0)
+            # a leading dense layer stays, so both layer kinds are covered
+            kw["first_k_dense"] = min(self.first_k_dense, 1)
         if self.ssm is not None:
             kw["ssm"] = replace(self.ssm, d_state=16, head_dim=32, chunk=16)
         return replace(self, **kw)

@@ -184,16 +184,18 @@ def _tree_depth(tree) -> int:
 
 def layer_flops(cfg: ModelConfig, kind: str, tree, batch: int, seq: int) -> float:
     """Forward FLOPs of one layer at (batch, seq). Matmuls: 2*params*tokens;
-    attention adds the 4*B*S*S_kv*H*hd score/value term; MoE counts only
-    active experts."""
+    attention adds the 4*B*S*S_kv*H*hd score/value term; a whole MoE layer
+    counts only active experts. A MoE layer's swap units: ``moe_attn``
+    (attention, router, shared experts) as a layer; ``experts`` as run,
+    every token through each expert of the group (``moe.routed_group``)."""
     T = batch * seq
     mm = _matmul_params(tree)
-    if kind in ("dense", "moe", "shared_attn") and cfg.moe is not None and kind == "moe":
+    if kind == "moe" and cfg.moe is not None:
         e = cfg.moe
         per_expert = 3 * cfg.d_model * e.d_expert
         mm = mm - e.n_routed * per_expert + e.top_k * per_expert
     f = 2.0 * mm * T
-    if kind in ("dense", "moe", "shared_attn"):
+    if kind in ("dense", "moe", "moe_attn", "shared_attn"):
         skv = seq if cfg.sliding_window is None else min(seq, cfg.sliding_window)
         hd = cfg.resolved_head_dim
         f += 4.0 * batch * seq * skv * cfg.n_heads * hd / 2  # causal halves it

@@ -36,11 +36,12 @@ from repro.core.swap_engine import BlockCache, MemoryLedger, SwapEngine
 from repro.kernels.qtensor import (QuantizedTensor, cast_unit_params,
                                    materialize_tree)
 from repro.kernels.swap_linear import vmem_bytes
+from repro.models import moe as moe_mod
 from repro.models.layers import linear, rms_norm, softcap
 from repro.store import build_store
 from repro.tracing import span
 from repro.models.transformer import (Model, apply_layer_jit,
-                                     apply_layer_paged)
+                                     apply_layer_paged, expert_group)
 
 
 @functools.partial(jax.jit, static_argnums=(0,))
@@ -106,7 +107,9 @@ class PassState:
     blocks carries everything needed to continue later — the activation,
     the position carrier, and the index of the next block — so a preempted
     request re-executes NOTHING on resume (bit-identical to an
-    uninterrupted pass). ``blocks`` AND the pipeline depth ``m`` are
+    uninterrupted pass); a block boundary inside a MoE layer leaves the
+    layer's state (``moe.begin``) as the activation. ``blocks`` AND the
+    pipeline depth ``m`` are
     snapshotted at pass start: a live budget re-plan
     (``MultiModelRuntime.replan_budgets``) only affects passes that start
     after it, never one already in flight — resuming old blocks at a new
@@ -130,13 +133,48 @@ class PassState:
 @dataclass
 class Unit:
     name: str
-    kind: str                 # embed | head | dense | moe | mamba2 | rwkv6 | shared_attn
+    kind: str                 # embed | head | dense | moe | moe_attn | experts
+    #                           | mamba2 | rwkv6 | shared_attn
     layer_id: Optional[int]
     params: dict
+    group: Optional[int] = None   # an ``experts`` unit's group in its layer
 
 
-def split_units(model: Model, params: dict) -> List[Unit]:
-    """The paper's get_layers(Net): one-time layer-wise division."""
+ROUTED = ("wi0", "wi1", "wo")      # a MoE layer's stacked routed experts
+
+
+def _part(a, idx):
+    """``a[idx]`` of a stacked weight, on the host; of a shape alone where
+    the units are split from ``Model.param_struct`` (a plan without
+    weights)."""
+    if isinstance(a, jax.ShapeDtypeStruct):
+        return jax.eval_shape(lambda x: x[idx], a)
+    return np.asarray(a[idx])
+
+
+def _moe_units(cfg, lid: int, p: dict) -> List[Unit]:
+    """A MoE layer as swap units: ``layerNNN_moe`` (attention, norms,
+    router, shared experts), then ``layerNNN_expertsG``, one per group of
+    ``moe.EXPERT_GROUP`` routed experts. The executor carries the layer's
+    state across them (``moe.begin``)."""
+    ffn = p["ffn"]
+    head = dict(p, ffn={k: v for k, v in ffn.items() if k not in ROUTED})
+    units = [Unit(f"layer{lid:03d}_moe", "moe_attn", lid, head)]
+    for g, (lo, n) in enumerate(moe_mod.expert_groups(cfg.moe.n_routed)):
+        units.append(Unit(f"layer{lid:03d}_experts{g}", "experts", lid,
+                          {k: _part(ffn[k], slice(lo, lo + n))
+                           for k in ROUTED}, group=g))
+    return units
+
+
+def split_units(model: Model, params: dict,
+                expert_groups: bool = True) -> List[Unit]:
+    """The paper's get_layers(Net): one-time layer-wise division. A MoE
+    layer is divided further, into its attention unit and its expert
+    groups (``_moe_units``): one layer of DeepSeek-V2-Lite outweighs the
+    block budget of a plan 3.4x over budget, its groups do not.
+    ``expert_groups=False`` keeps each MoE layer whole. ``params`` may be
+    ``Model.param_struct()``: units of shapes, for planning alone."""
     cfg = model.cfg
     units: List[Unit] = []
     head_p = {k: params[k] for k in ("embed", "frontend", "mask_emb")
@@ -150,8 +188,12 @@ def split_units(model: Model, params: dict) -> List[Unit]:
             continue
         stacked = params["segments"][si]
         for j, lid in enumerate(seg.layer_ids):
-            p = jax.tree.map(lambda a, _j=j: np.asarray(a[_j]), stacked)
-            units.append(Unit(f"layer{lid:03d}_{seg.kind}", seg.kind, lid, p))
+            p = jax.tree.map(lambda a, _j=j: _part(a, _j), stacked)
+            if seg.kind == "moe" and expert_groups:
+                units.extend(_moe_units(cfg, lid, p))
+            else:
+                units.append(Unit(f"layer{lid:03d}_{seg.kind}", seg.kind,
+                                  lid, p))
     tail = {"final_norm": params["final_norm"]}
     if "lm_head" in params:
         tail["lm_head"] = params["lm_head"]
@@ -163,19 +205,22 @@ def split_units(model: Model, params: dict) -> List[Unit]:
     return units
 
 
+def _nbytes(leaf) -> int:
+    return int(np.prod(leaf.shape)) * np.dtype(leaf.dtype).itemsize
+
+
 def unit_infos(model: Model, units: Sequence[Unit], batch: int,
                seq: int) -> List[LayerInfo]:
     """Model info table rows (paper Table 2) aligned 1:1 with units."""
     cfg = model.cfg
     rows = []
     for u in units:
-        size = sum(np.asarray(l).nbytes for l in jax.tree.leaves(u.params))
+        size = sum(_nbytes(l) for l in jax.tree.leaves(u.params))
         depth = len(jax.tree.leaves(u.params))
         if u.kind == "embed":
             f = 2.0 * batch * seq * cfg.d_model
         elif u.kind == "head":
-            has_head = "lm_head" in u.params
-            f = 2.0 * batch * cfg.d_model * cfg.vocab_size * (1 if has_head else 1)
+            f = 2.0 * batch * cfg.d_model * cfg.vocab_size
         else:
             kind = "dense" if u.kind == "shared_attn" else u.kind
             f = layer_flops(cfg, kind, u.params, batch, seq)
@@ -410,6 +455,14 @@ class SwappedModel:
         # calibration seam (repro/calibrate): fn(Unit, params) -> params,
         # applied after swap-in inside forward_partial's unit loop
         self.param_override: Optional[Any] = None
+        # expert-group counters over every pass: (MoE layer, expert) pairs
+        # some token routed to, summed on the device (read it only where a
+        # sync is fine), and experts whose weights the passes streamed
+        self.experts_routed = jnp.zeros((), jnp.int32)
+        self.experts_streamed = 0
+        self._groups = ([(jnp.asarray(lo, jnp.int32), n) for lo, n in
+                         moe_mod.expert_groups(self.cfg.moe.n_routed)]
+                        if self.cfg.moe is not None else [])
 
     # ------------------------------------------------------------ partition
     def partition(self, budget: int, dm: DelayModel, batch: int, seq: int,
@@ -438,9 +491,31 @@ class SwappedModel:
         return head_logits(self.cfg, uparams["final_norm"],
                            uparams["lm_head"], h)
 
+    def _experts(self, unit: Unit, uparams: dict, carry):
+        """One expert-group unit on its layer's state (``moe.begin``); the
+        layer's output x after its last group. Counts the group's experts
+        as streamed, and those some token routed to, on the device."""
+        lo, n = self._groups[unit.group]
+        last = unit.group == len(self._groups) - 1
+        w = cast_unit_params(uparams, jnp.dtype(self.cfg.dtype))
+        with span("exec.experts", layer=unit.layer_id, group=unit.group):
+            out, self.experts_routed = expert_group(
+                w, carry, lo, self.experts_routed, last)
+        self.experts_streamed += n
+        return out
+
+    def expert_counters(self) -> Dict[str, Any]:
+        """The expert-group counters as they stand, without a sync:
+        ``experts_routed`` is a device scalar (``int()`` of it waits for
+        the programs that count it)."""
+        return {"experts_routed": self.experts_routed,
+                "experts_streamed": self.experts_streamed}
+
     def _apply_unit(self, unit: Unit, uparams: dict, x, positions, batch,
                     collect: Optional[dict] = None):
         cfg = self.cfg
+        if unit.kind == "experts":
+            return self._experts(unit, uparams, x), positions
         if unit.kind == "embed":
             # embeddings are gather/frontend consumers: dequantize at use
             x, positions = self.model._embed(
@@ -500,7 +575,8 @@ class SwappedModel:
         cfg = self.cfg
         B, S = prompt_tokens.shape
         caches = {i: self._unit_cache_struct(u, B, max_len)
-                  for i, u in enumerate(self.units) if u.layer_id is not None}
+                  for i, u in enumerate(self.units)
+                  if u.layer_id is not None and u.kind != "experts"}
 
         unit_names = [u.name for u in self.units]
 
@@ -526,6 +602,8 @@ class SwappedModel:
                                     materialize_tree(p), batch, "decode")
                             elif unit.kind == "head":
                                 last_logits = self._head_logits(p, x)
+                            elif unit.kind == "experts":
+                                x = self._experts(unit, p, x)
                             else:
                                 kind = "dense" if unit.kind == "shared_attn" else unit.kind
                                 pc = cast_unit_params(p, jnp.dtype(cfg.dtype))
@@ -585,6 +663,8 @@ class SwappedModel:
                                     materialize_tree(p), batch, "decode")
                             elif unit.kind == "head":
                                 logits = self._head_logits(p, x)
+                            elif unit.kind == "experts":
+                                x = self._experts(unit, p, x)
                             else:
                                 kind = ("dense" if unit.kind == "shared_attn"
                                         else unit.kind)

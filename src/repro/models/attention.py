@@ -18,7 +18,8 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.distributed.sharding import ParamDef
-from repro.models.layers import apply_rope, linear, rope_angles, softcap
+from repro.models.layers import (apply_rope, linear, rope_angles,
+                                 rope_cos_sin_scale, softcap, yarn_mscale)
 
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 LARGE_WINDOW = 1 << 30
@@ -351,32 +352,89 @@ def mla_defs(cfg: ModelConfig) -> dict:
     }
 
 
+def mla_scale(cfg: ModelConfig) -> float:
+    """MLA's softmax scale: (qk_nope + qk_rope)^-0.5, times YaRN's
+    mscale(factor, mscale_all_dim) squared where the rope is YaRN."""
+    m = cfg.mla
+    scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
+    s = cfg.rope_scaling
+    if s is not None and s.mscale_all_dim:
+        scale *= yarn_mscale(s.factor, s.mscale_all_dim) ** 2
+    return scale
+
+
+def _mla_project(cfg: ModelConfig, p: dict, x: jax.Array,
+                 positions: jax.Array):
+    """x [B,S,D] -> q_nope [B,S,H,nd], q_rope [B,S,H,rd] (rotated), the
+    normed latent c_kv [B,S,r] and the shared k_rope [B,S,rd] (rotated)."""
+    from repro.models.layers import rms_norm
+    m = cfg.mla
+    B, S, D = x.shape
+    H = cfg.n_heads
+    nd, rd = m.qk_nope_head_dim, m.qk_rope_head_dim
+    q = linear(x, p["wq"]).reshape(B, S, H, nd + rd)
+    q_nope, q_rope = q[..., :nd], q[..., nd:]
+    c_kv = rms_norm(x @ p["w_dkv"], p["kv_norm"], cfg.norm_eps)    # [B,S,r]
+    k_rope = (x @ p["w_krope"]).reshape(B, S, 1, rd)
+    ang = rope_angles(positions, rd, cfg.rope_theta,
+                      scaling=cfg.rope_scaling)
+    c = rope_cos_sin_scale(cfg.rope_scaling)
+    q_rope = apply_rope(q_rope, ang)
+    k_rope = apply_rope(k_rope, ang)
+    if c != 1.0:
+        q_rope, k_rope = q_rope * c, k_rope * c
+    return q_nope, q_rope, c_kv, k_rope[:, :, 0, :]
+
+
+def mla_paged_qkv(cfg: ModelConfig, p: dict, x: jax.Array,
+                  positions: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """One decode token per sequence (x [B,1,D]) in absorbed form: the query
+    in latent space q = [q_nope W_uk, q_rope] [B, H, r+rd] and the token's
+    latent row [c_kv, k_rope] [B, 1, r+rd]. Scores against the latent rows
+    are MLA's q.k; the latent rows weighted by the softmax, first r lanes,
+    are the values before W_uv (:func:`mla_paged_out`). So decode is MQA
+    with one "KV head" of width r+rd."""
+    m = cfg.mla
+    B = x.shape[0]
+    H, nd, r = cfg.n_heads, m.qk_nope_head_dim, m.kv_lora_rank
+    q_nope, q_rope, c_kv, k_rope = _mla_project(cfg, p, x, positions)
+    w_uk = p["w_uk"].reshape(r, H, nd)
+    q_lat = jnp.einsum("bshn,rhn->bshr", q_nope, w_uk.astype(q_nope.dtype))
+    q = jnp.concatenate([q_lat, q_rope.astype(q_lat.dtype)], -1)
+    lat = jnp.concatenate([c_kv, k_rope.astype(c_kv.dtype)], -1)
+    return q[:, 0], lat.reshape(B, 1, r + m.qk_rope_head_dim)
+
+
+def mla_paged_out(cfg: ModelConfig, p: dict, out: jax.Array,
+                  dtype) -> jax.Array:
+    """Paged attention's output over the latent rows [B, H, r+rd] (or
+    [B, 1, H, r+rd]): its first r lanes through W_uv, then wo -> [B,1,D]."""
+    m = cfg.mla
+    H, r, vd = cfg.n_heads, m.kv_lora_rank, m.v_head_dim
+    B = out.shape[0]
+    lat = out.reshape(B, H, -1)[..., :r]
+    w_uv = p["w_uv"].reshape(r, H, vd)
+    o = jnp.einsum("bhr,rhv->bhv", lat.astype(dtype), w_uv.astype(dtype))
+    return linear(o.reshape(B, 1, H * vd), p["wo"])
+
+
 def mla_apply(cfg: ModelConfig, p: dict, x: jax.Array, positions: jax.Array,
               cache: Optional[dict], decode_pos: Optional[jax.Array],
               chunk: int = 1024) -> Tuple[jax.Array, Optional[dict]]:
     """MLA. Cache holds the COMPRESSED latents (c_kv, k_rope) — the memory win.
     Prefill: up-project per block. Decode: absorbed attention in latent space
     (W_uk folded into q, W_uv applied after) so per-step FLOPs stay O(r)."""
-    from repro.models.layers import rms_norm
     m = cfg.mla
     B, S, D = x.shape
     H = cfg.n_heads
     nd, rd, vd, r = m.qk_nope_head_dim, m.qk_rope_head_dim, m.v_head_dim, m.kv_lora_rank
-    scale = (nd + rd) ** -0.5
-
-    q = linear(x, p["wq"]).reshape(B, S, H, nd + rd)
-    q_nope, q_rope = q[..., :nd], q[..., nd:]
-    c_kv = rms_norm(x @ p["w_dkv"], p["kv_norm"], cfg.norm_eps)    # [B,S,r]
-    k_rope = (x @ p["w_krope"]).reshape(B, S, 1, rd)
-
-    ang = rope_angles(positions, rd, cfg.rope_theta)
-    q_rope = apply_rope(q_rope, ang)
-    k_rope = apply_rope(k_rope, ang)
+    scale = mla_scale(cfg)
+    q_nope, q_rope, c_kv, k_rope = _mla_project(cfg, p, x, positions)
 
     if cache is not None and decode_pos is not None:
         upd2 = jax.vmap(lambda c, u, i: jax.lax.dynamic_update_slice(c, u, (i, 0)))
         cache = {"c_kv": upd2(cache["c_kv"], c_kv, decode_pos),
-                 "k_rope": upd2(cache["k_rope"], k_rope[:, :, 0, :], decode_pos)}
+                 "k_rope": upd2(cache["k_rope"], k_rope, decode_pos)}
         # absorbed decode: q_nope' = q_nope @ W_uk^T  -> latent space
         w_uk = p["w_uk"].reshape(r, H, nd)
         q_lat = jnp.einsum("bshn,rhn->bshr", q_nope, w_uk)          # [B,1,H,r]
@@ -397,10 +455,10 @@ def mla_apply(cfg: ModelConfig, p: dict, x: jax.Array, positions: jax.Array,
     # train / prefill: materialize k, v from latents for this block
     k_nope = (c_kv @ p["w_uk"]).reshape(B, S, H, nd)
     v = (c_kv @ p["w_uv"]).reshape(B, S, H, vd)
-    k = jnp.concatenate([k_nope, jnp.broadcast_to(k_rope, (B, S, H, rd))], axis=-1)
+    k = jnp.concatenate([k_nope, jnp.broadcast_to(k_rope[:, :, None, :],
+                                                  (B, S, H, rd))], axis=-1)
     qf = jnp.concatenate([q_nope, q_rope], axis=-1)
-    # pad v to qk dim for the shared kernel? no — online_attention is dim-agnostic
     out = online_attention(qf, k, v, positions, None, causal=not cfg.is_encoder,
                            window=None, scale=scale, logit_cap=None, chunk=chunk)
     out = linear(out.reshape(B, S, H * vd).astype(x.dtype), p["wo"])
-    return out, {"c_kv": c_kv, "k_rope": k_rope[:, :, 0, :]}
+    return out, {"c_kv": c_kv, "k_rope": k_rope}

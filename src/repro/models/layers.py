@@ -9,10 +9,12 @@ the seed.
 """
 from __future__ import annotations
 
+import math
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.distributed.sharding import ParamDef
@@ -38,17 +40,58 @@ def layer_norm(x: jax.Array, w: jax.Array, b: jax.Array,
 
 
 # ------------------------------------------------------------------ rotary
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's magnitude correction for a context stretched ``factor`` times
+    (DeepSeek-V2's ``yarn_get_mscale``)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(head_dim: int, theta: float, s) -> np.ndarray:
+    """YaRN's rotary frequencies over ``head_dim`` (DeepSeek-V2's published
+    form): rotation i < dim/2 keeps its frequency theta^(-2i/dim) below the
+    ramp, is divided by ``s.factor`` above it, and blends in between; the
+    ramp runs from the rotation that turns ``s.beta_fast`` times over the
+    original context to the one that turns ``s.beta_slow`` times."""
+    def corr(rotations: float) -> float:
+        return (head_dim * math.log(s.original_max_position_embeddings
+                                    / (2 * math.pi * rotations))
+                / (2 * math.log(theta)))
+    low = max(math.floor(corr(s.beta_fast)), 0)
+    high = min(math.ceil(corr(s.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    i = np.arange(head_dim // 2, dtype=np.float64)
+    ramp = np.clip((i - low) / (high - low), 0.0, 1.0)
+    base = theta ** (-2.0 * i / head_dim)
+    return (base * (ramp / s.factor + (1.0 - ramp))).astype(np.float32)
+
+
+def rope_cos_sin_scale(s) -> float:
+    """Factor YaRN puts on cos and sin (1 where ``mscale`` equals
+    ``mscale_all_dim``, as in DeepSeek-V2-Lite); rotation is linear, so the
+    rotated vector scales by it."""
+    if s is None:
+        return 1.0
+    return (yarn_mscale(s.factor, s.mscale)
+            / yarn_mscale(s.factor, s.mscale_all_dim))
+
+
 def rope_angles(positions: jax.Array, head_dim: int, theta: float,
-                mrope_sections: Optional[Tuple[int, int, int]] = None) -> jax.Array:
-    """positions: [B, S] (rope) or [B, S, 3] (mrope) -> angles [B, S, head_dim/2]."""
+                mrope_sections: Optional[Tuple[int, int, int]] = None,
+                scaling=None) -> jax.Array:
+    """positions: [B, S] (rope) or [B, S, 3] (mrope) -> angles [B, S, head_dim/2].
+    ``scaling`` (a ``YaRNConfig``) takes the frequencies from
+    :func:`yarn_inv_freq`."""
     half = head_dim // 2
-    inv_freq = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if scaling is not None:
+        inv_freq = jnp.asarray(yarn_inv_freq(head_dim, theta, scaling))
+    else:
+        inv_freq = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
     if mrope_sections is None:
         pos = positions.astype(jnp.float32)
         return pos[..., None] * inv_freq
     # M-RoPE (Qwen2-VL): frequency slots are split into (t, h, w) sections,
     # each driven by its own position stream. Static numpy: never traced.
-    import numpy as np
     sec = np.asarray(mrope_sections)
     assert int(sec.sum()) == half, (mrope_sections, half)
     section_id = jnp.asarray(np.repeat(np.arange(3), sec))  # [half]

@@ -162,7 +162,7 @@ def apply_layer(cfg: ModelConfig, kind: str, p: dict, x: jax.Array,
         out, sh2n = ssm_mod.rwkv6_channel_mix(cfg, p, xn, sh2)
         return x + out, {"S": S, "shift1": sh1n, "shift2": sh2n}, aux
 
-    # dense / moe / shared_attn transformer block
+    # dense / moe / moe_attn / shared_attn transformer block
     h = rms_norm(x, p["ln1"], cfg.norm_eps, plus_one=cfg.post_norms)
     if cfg.mla is not None:
         a_out, new_cache = attn_mod.mla_apply(cfg, p["attn"], h, positions,
@@ -170,21 +170,29 @@ def apply_layer(cfg: ModelConfig, kind: str, p: dict, x: jax.Array,
     else:
         a_out, new_cache = attn_mod.gqa_apply(cfg, p["attn"], h, positions,
                                               is_local, cache, decode_pos)
-    x, aux = _block_ffn(cfg, kind, p, x, a_out)
+    x, aux = _block_ffn(cfg, kind, p, x, a_out, mode)
     return x, new_cache, aux
 
 
 def _block_ffn(cfg: ModelConfig, kind: str, p: dict, x: jax.Array,
-               a_out: jax.Array) -> Tuple[jax.Array, jax.Array]:
+               a_out: jax.Array, mode: str) -> Tuple[Any, jax.Array]:
     """A transformer block after its attention: residual, then the dense or
-    routed FFN with its own residual. Returns (x, aux)."""
+    routed FFN with its own residual. Returns (x, aux). A ``moe`` layer
+    routes drop-free outside training (``moe.moe_serve``); ``moe_attn``,
+    the part of a MoE layer before its routed experts (the swapped
+    executor's ``layerNNN_moe`` unit), returns the layer's state for its
+    expert groups (``moe.begin``) in place of x."""
     aux = jnp.zeros((), jnp.float32)
     if cfg.post_norms:
         a_out = rms_norm(a_out, p["post_ln1"], cfg.norm_eps, plus_one=True)
     x = x + a_out
     h = rms_norm(x, p["ln2"], cfg.norm_eps, plus_one=cfg.post_norms)
+    if kind == "moe_attn":
+        return moe_mod.begin(cfg, p["ffn"], x, h), aux
+    if kind == "moe" and mode != "train":
+        return moe_mod.moe_serve(cfg, p["ffn"], x, h), aux
     if kind == "moe":
-        f_out, aux = moe_mod.moe_apply(cfg, p["ffn"], h)
+        f_out, aux = moe_mod.moe_train(cfg, p["ffn"], h)
     else:
         f_out = mlp_apply(cfg, p["ffn"], h)
     if cfg.post_norms:
@@ -203,40 +211,71 @@ def _block_ffn(cfg: ModelConfig, kind: str, p: dict, x: jax.Array,
 apply_layer_jit = jax.jit(apply_layer, static_argnums=(0, 1, 5, 8))
 
 
+@functools.partial(jax.jit, static_argnums=(4,))
+def expert_group(w: dict, carry: dict, lo: jax.Array, routed: jax.Array,
+                 last: bool):
+    """One expert-group unit of a MoE layer as one compiled program (the
+    only one whose name holds ``expert_group``): the layer's state
+    ``carry`` (``moe.begin``) with experts [lo, lo + n) of ``w`` added, and
+    on the layer's ``last`` group its output x in the state's place. Also
+    returns ``routed`` plus how many of the group's experts some token
+    routed to, a count kept on the device."""
+    n = w["wi0"].shape[0]
+    routed = routed + moe_mod.routed_count(carry["top_e"], lo, n)
+    carry = moe_mod.group(carry, w, lo)
+    return (moe_mod.end(carry) if last else carry), routed
+
+
 @functools.partial(jax.jit, static_argnums=(0,))
 def _paged_qkv(cfg: ModelConfig, p: dict, x: jax.Array, positions):
-    """The decode token's q [B, H, hd] and k, v [B, KV, hd]."""
+    """The decode token's q [B, H, hd] and k, v [B, KV, hd]; under MLA the
+    latent-space q [B, H, r+rd], the token's latent row [B, 1, r+rd] as k,
+    and no v (``attention.mla_paged_qkv``)."""
     h = rms_norm(x, p["ln1"], cfg.norm_eps, plus_one=cfg.post_norms)
+    if cfg.mla is not None:
+        q, lat = attn_mod.mla_paged_qkv(cfg, p["attn"], h, positions)
+        return q, lat, None
     q, k, v = attn_mod.gqa_qkv(cfg, p["attn"], h, positions)
     return q[:, 0], k[:, 0], v[:, 0]
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1))
 def _paged_out(cfg: ModelConfig, kind: str, p: dict, x: jax.Array,
-               attn: jax.Array) -> jax.Array:
+               attn: jax.Array):
     """The rest of the layer after paged attention: ``attn`` [B, H, hd]
-    (or [B, 1, H, hd]) through ``wo``, the residual and the FFN."""
+    (or [B, 1, H, hd]) through ``wo`` (under MLA its first r lanes through
+    W_uv first), the residual and the FFN; a ``moe_attn`` unit returns the
+    layer's state for its expert groups."""
     B, S, _ = x.shape
-    a_out = linear(attn.reshape(B, S, -1).astype(x.dtype), p["attn"]["wo"])
-    return _block_ffn(cfg, kind, p, x, a_out)[0]
+    if cfg.mla is not None:
+        a_out = attn_mod.mla_paged_out(cfg, p["attn"], attn, x.dtype)
+    else:
+        a_out = linear(attn.reshape(B, S, -1).astype(x.dtype),
+                       p["attn"]["wo"])
+    return _block_ffn(cfg, kind, p, x, a_out, "decode")[0]
 
 
 def apply_layer_paged(cfg: ModelConfig, kind: str, p: dict, x: jax.Array,
-                      positions: jax.Array, is_local: bool, paged
-                      ) -> jax.Array:
+                      positions: jax.Array, is_local: bool, paged):
     """One decode token per sequence ([B, 1, D]) through the paged KV cache.
 
     ``paged`` is a layer-bound attend hook (``PagedBatchView.bind``): it
-    appends the new K/V to each sequence's pages on the device, then runs
-    paged attention through the page table. The projections before it and
-    the FFN after it are compiled programs, like :data:`apply_layer_jit`,
-    and nothing between the four waits for the device or slices on the
-    host. Returns the layer's output x."""
+    appends the new K/V (under MLA, the latent row) to each sequence's
+    pages on the device, then runs paged attention through the page table.
+    The projections before it and the FFN after it are compiled programs,
+    like :data:`apply_layer_jit`, and nothing between the four waits for
+    the device or slices on the host. Returns the layer's output x (the
+    layer's state for a ``moe_attn`` unit)."""
     assert x.shape[1] == 1, "paged attention is the single-token decode path"
     q, k, v = _paged_qkv(cfg, p, x, positions)
-    out = paged.attend(q, k, v, scale=attn_mod.attn_scale(cfg),
-                       window=attn_mod.paged_window(cfg, is_local),
-                       softcap=cfg.attn_logit_softcap)
+    if cfg.mla is not None:
+        # MQA over the latent rows: one "KV head" of width r+rd, the pool
+        # as both keys and values
+        out = paged.attend(q, k, None, scale=attn_mod.mla_scale(cfg))
+    else:
+        out = paged.attend(q, k, v, scale=attn_mod.attn_scale(cfg),
+                           window=attn_mod.paged_window(cfg, is_local),
+                           softcap=cfg.attn_logit_softcap)
     return _paged_out(cfg, kind, p, x, out)
 
 

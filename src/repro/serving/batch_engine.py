@@ -105,6 +105,7 @@ class BatchDecodeEngine:
         self._on_retire: Dict[int, Optional[Callable]] = {}
         self._step_no = 0
         self._kv_counters = kv.counters()    # as of the last step's end
+        self._expert_counters = sm.expert_counters()    # likewise
         self._lock = threading.Lock()        # pending / done / callbacks
         self._drive = threading.Lock()       # one step() at a time
 
@@ -168,7 +169,7 @@ class BatchDecodeEngine:
             assert stats is not None
             rows = self.kv.rows(req.rid, 0, len(tokens))
             for lid, c in state.caches.items():
-                self.kv.append(lid, rows, c["k"], c["v"])
+                self.kv.ingest(lid, rows, c)
             return int(np.argmax(np.asarray(state.logits)[0, -1]))
 
     # ------------------------------------------------------------ stepping
@@ -301,6 +302,8 @@ class BatchDecodeEngine:
         self.trace.append(tr)
         self._step_no += 1
         self._kv_counters = self.kv.counters()
+        # a reference to the device count, not a read: no sync per step
+        self._expert_counters = self.sm.expert_counters()
         return tr
 
     # ------------------------------------------------------------ driving
@@ -329,6 +332,9 @@ class BatchDecodeEngine:
 
     # ------------------------------------------------------------ stats
     def stats(self) -> Dict[str, float]:
+        """The engine's counters. ``experts_routed`` is read from the device
+        here, once a read, as of the last step's end (it waits for that
+        step's programs); ``experts_streamed`` beside it."""
         decoded = [t for t in self.trace if t.batch]
         occ = [t.occupancy for t in decoded]
         return {
@@ -347,4 +353,5 @@ class BatchDecodeEngine:
             "admit_wait_s": self.admit_wait_s,
             "admitted": float(self.admitted),
             **{f"kv_{k}": float(v) for k, v in self._kv_counters.items()},
+            **{k: float(v) for k, v in self._expert_counters.items()},
         }

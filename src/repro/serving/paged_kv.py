@@ -9,6 +9,13 @@ owns an ordered page list. A page spans ALL layers (one alloc decision per
 
     page_bytes = 2 (K+V) * n_layers * page_tokens * KV * hd * itemsize.
 
+Under multi-head latent attention (MLA) a layer keeps one LATENT pool,
+[1, P+1, T, r+rd]: each token's normed latent c_kv and its shared rotated
+k_rope, side by side (576 values in DeepSeek-V2-Lite), which absorbed
+decode reads as both keys and values, so
+
+    page_bytes = n_layers * page_tokens * (r + rd) * itemsize.
+
 Pages are charged to the same :class:`~repro.core.swap_engine.MemoryLedger`
 as weight blocks, under one per-sequence key whose value is re-charged with
 delta semantics as the sequence grows — KV pages and weight-block residency
@@ -69,9 +76,28 @@ def _kv_append(k_pool, v_pool, rows, k, v):
     return put(k_pool, k), put(v_pool, v)
 
 
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _latent_append(pool, rows, *parts):
+    """Scatter token rows into one layer's [1, P, T, r+rd] latent pool in
+    place: the rows are ``parts`` joined on their last axis (the decode
+    step's [B, 1, r+rd] latent rows, or a prefill's c_kv [1, S, r] and
+    k_rope [1, S, rd]), one per column of ``rows``."""
+    x = jnp.concatenate([p.astype(pool.dtype) for p in parts], -1)
+    x = x.reshape(1, -1, pool.shape[-1])
+    return pool.at[:, rows[0], rows[1]].set(x, unique_indices=True)
+
+
+def latent_width(cfg: ModelConfig) -> int:
+    """Values per token of an MLA layer's latent pool: c_kv and k_rope."""
+    return cfg.mla.kv_lora_rank + cfg.mla.qk_rope_head_dim
+
+
 def page_bytes_for(cfg: ModelConfig, page_tokens: int) -> int:
-    """Ledger cost of one page: K+V for every layer's slice of the page."""
+    """Ledger cost of one page: K+V for every layer's slice of the page
+    (under MLA, the latent row)."""
     itemsize = jnp.dtype(cfg.dtype).itemsize
+    if cfg.mla is not None:
+        return cfg.n_layers * page_tokens * latent_width(cfg) * itemsize
     return (2 * cfg.n_layers * page_tokens
             * cfg.n_kv_heads * cfg.resolved_head_dim * itemsize)
 
@@ -86,27 +112,33 @@ class PagedKVCache:
     def __init__(self, cfg: ModelConfig, ledger: MemoryLedger, *,
                  page_tokens: int = 16, max_pages: int = 64,
                  name: str = "kv"):
-        if cfg.mla is not None or any(
-                k not in ("dense", "moe") for k in cfg.layer_kinds()):
+        if any(k not in ("dense", "moe") for k in cfg.layer_kinds()):
             raise ValueError(
-                f"{cfg.name}: paged KV serving covers uniform GQA/MHA "
-                f"attention stacks (MLA and SSM/shift state layers keep the "
-                f"contiguous legacy path)")
+                f"{cfg.name}: paged KV serving covers attention stacks "
+                f"(GQA/MHA or MLA); SSM/shift state layers keep the "
+                f"contiguous legacy path")
         self.cfg = cfg
         self.ledger = ledger
         self.page_tokens = int(page_tokens)
         self.max_pages = int(max_pages)
         self.name = name
         self.page_bytes = page_bytes_for(cfg, self.page_tokens)
-        KV, hd = cfg.n_kv_heads, cfg.resolved_head_dim
+        self.latent = cfg.mla is not None
         dt = jnp.dtype(cfg.dtype)
         # page 0 is a permanently-zero SENTINEL: page tables are padded with
         # it past a sequence's pages, so the kernel's gather always lands on
         # a real (masked) page. KV heads lead ([KV, P, T, hd]): the kernel
-        # streams one (page_tokens, hd) tile per (kv head, page)
-        shape = (KV, self.max_pages + 1, self.page_tokens, hd)
+        # streams one (page_tokens, hd) tile per (kv head, page). An MLA
+        # layer has one latent pool ([1, P, T, r+rd]) and no V pools
+        if self.latent:
+            shape = (1, self.max_pages + 1, self.page_tokens,
+                     latent_width(cfg))
+        else:
+            shape = (cfg.n_kv_heads, self.max_pages + 1, self.page_tokens,
+                     cfg.resolved_head_dim)
         self.k_pools = [jnp.zeros(shape, dt) for _ in range(cfg.n_layers)]
-        self.v_pools = [jnp.zeros(shape, dt) for _ in range(cfg.n_layers)]
+        self.v_pools = ([] if self.latent else
+                        [jnp.zeros(shape, dt) for _ in range(cfg.n_layers)])
         self._free: List[int] = list(range(self.max_pages, 0, -1))
         self._pages: Dict[object, List[int]] = {}
         self._len: Dict[object, int] = {}
@@ -222,13 +254,30 @@ class PagedKVCache:
                                       np.int32))
 
     def append(self, layer: int, rows: jax.Array, k: jax.Array,
-               v: jax.Array) -> None:
+               v: Optional[jax.Array]) -> None:
         """Write token rows ``k``/``v`` ([..., KV, hd], one per column of
-        ``rows``) into the layer's pools on the device, in place. Only the
-        dispatch runs here; nothing waits for the device."""
+        ``rows``) into the layer's pools on the device, in place; under
+        MLA ``k`` is the latent rows [..., r+rd] and ``v`` is None. Only
+        the dispatch runs here; nothing waits for the device."""
         with self._host("kv.write"):
-            self.k_pools[layer], self.v_pools[layer] = _kv_append(
-                self.k_pools[layer], self.v_pools[layer], rows, k, v)
+            if self.latent:
+                self.k_pools[layer] = _latent_append(self.k_pools[layer],
+                                                     rows, k)
+            else:
+                self.k_pools[layer], self.v_pools[layer] = _kv_append(
+                    self.k_pools[layer], self.v_pools[layer], rows, k, v)
+        self.rows_written += int(rows.shape[1])
+
+    def ingest(self, layer: int, rows: jax.Array, cache: dict) -> None:
+        """Seed a layer's pages from a prefill's cache, in place on the
+        device: its K/V ({"k", "v"} [1, S, KV, hd]) or, under MLA, its
+        latents ({"c_kv" [1, S, r], "k_rope" [1, S, rd]})."""
+        if not self.latent:
+            self.append(layer, rows, cache["k"], cache["v"])
+            return
+        with self._host("kv.write"):
+            self.k_pools[layer] = _latent_append(
+                self.k_pools[layer], rows, cache["c_kv"], cache["k_rope"])
         self.rows_written += int(rows.shape[1])
 
     # ------------------------------------------------------------ views
@@ -248,8 +297,7 @@ class PagedKVCache:
     def device_pool_bytes(self) -> int:
         """Bytes the pools hold on the device (outside the ledger, which
         charges allocated pages only)."""
-        return sum(k.nbytes + v.nbytes
-                   for k, v in zip(self.k_pools, self.v_pools))
+        return sum(p.nbytes for p in self.k_pools + self.v_pools)
 
     def counters(self) -> Dict[str, float]:
         """The KV path's counters and the device-pool gauge."""
@@ -315,10 +363,12 @@ class PagedBatchView:
                softcap: Optional[float] = None):
         """Append this layer's new K/V ([B, KV, hd]) to each sequence's
         pages on the device, then attend q ([B, H, hd]) through the page
-        table."""
+        table. Under MLA ``k_new`` is the latent row ([B, 1, r+rd]),
+        ``v_new`` None, and the latent pool is both keys and values."""
         self.kv.append(layer, self._rows, k_new, v_new)
-        return ops.paged_attention(q, self.kv.k_pools[layer],
-                                   self.kv.v_pools[layer], self.page_table,
+        k_pool = self.kv.k_pools[layer]
+        v_pool = k_pool if self.kv.latent else self.kv.v_pools[layer]
+        return ops.paged_attention(q, k_pool, v_pool, self.page_table,
                                    self.seq_lens, scale=scale, window=window,
                                    softcap=softcap)
 

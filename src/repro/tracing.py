@@ -11,13 +11,17 @@ only switch.
 Spans, by thread (each nests in the one above it):
 
   executor   ``decode_step`` (step) > ``exec`` (block) > ``exec.sync``,
-             ``kv.write`` (the dispatch of a layer's K/V append);
+             ``kv.write`` (the dispatch of a layer's K/V append),
+             ``exec.experts`` (layer, group: one expert-group unit's
+             program);
              ``admit`` (rid) > ``prefill`` > ``exec`` > ...;
              ``swap.wait`` and ``swap.out`` between the blocks of a pass
   loader     ``swap.read``, ``swap.unpack``, ``swap.dispatch`` (unit)
 
 Counters (bytes, seconds, counts) live on the objects that own them
-(``SwapStats``, ``PagedKVCache``, ``BatchDecodeEngine.stats()``).
+(``SwapStats``, ``PagedKVCache``, ``BatchDecodeEngine.stats()``); the
+expert-group counters ``experts_routed`` (on the device) and
+``experts_streamed`` on ``SwappedModel``, read through ``stats()``.
 """
 from __future__ import annotations
 

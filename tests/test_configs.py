@@ -21,7 +21,8 @@ ASSIGNED = {
     "gemma2-9b": dict(n_layers=42, d_model=3584, n_heads=16, n_kv_heads=8,
                       d_ff=14336, vocab_size=256000),
     "deepseek-v2-lite-16b": dict(n_layers=27, d_model=2048, n_heads=16,
-                                 d_ff=1408, vocab_size=102400),
+                                 d_ff=10944, vocab_size=102400,
+                                 first_k_dense=1),
     "llama4-scout-17b-a16e": dict(n_layers=48, d_model=5120, n_heads=40,
                                   n_kv_heads=8, d_ff=8192, vocab_size=202048),
 }
@@ -47,6 +48,10 @@ def test_family_traits():
     assert get_arch("deepseek-v2-lite-16b").mla.kv_lora_rank == 512
     m = get_arch("deepseek-v2-lite-16b").moe
     assert (m.n_routed, m.top_k, m.n_shared) == (64, 6, 2)
+    assert (m.d_expert, m.d_shared, m.norm_topk_prob) == (1408, 2816, False)
+    kinds = get_arch("deepseek-v2-lite-16b").layer_kinds()
+    assert kinds[0] == "dense" and set(kinds[1:]) == {"moe"}
+    assert get_arch("deepseek-v2-lite-16b").rope_scaling.factor == 40.0
     m = get_arch("llama4-scout-17b-a16e").moe
     assert (m.n_routed, m.top_k, m.n_shared) == (16, 1, 1)
     assert get_arch("gemma2-9b").attn_logit_softcap == 50.0

@@ -265,11 +265,52 @@ def test_append_donates_the_old_pools():
     assert kv.device_pool_bytes == cfg.n_layers * 2 * new_k.nbytes
 
 
+@pytest.mark.parametrize("case", ["decode", "prefill"])
+def test_latent_append_matches_numpy_scatter(case):
+    """MLA's latent rows land where the page table says, in place: a
+    decode step's [B, 1, r+rd] rows, or a prefill's c_kv and k_rope side
+    by side; page 0 stays zero."""
+    cfg = dataclasses.replace(ARCHS["deepseek-v2-lite-16b"].reduced(),
+                              dtype="float32")
+    r, rd = cfg.mla.kv_lora_rank, cfg.mla.qk_rope_head_dim
+    kv = PagedKVCache(cfg, MemoryLedger(None), page_tokens=4, max_pages=8)
+    rng = np.random.default_rng(0)
+    want = np.zeros(kv.k_pools[1].shape, np.float32)
+    kv.alloc("a", 7)
+    kv.alloc("b", 3)
+    pt, _ = kv.page_table(["a", "b"])
+    if case == "decode":
+        lat = rng.normal(size=(2, 1, r + rd)).astype(np.float32)
+        kv.append(1, kv.last_rows(["a", "b"]), jnp.asarray(lat), None)
+        want[0, pt[0, 6 // 4], 6 % 4] = lat[0, 0]
+        want[0, pt[1, 2 // 4], 2 % 4] = lat[1, 0]
+    else:
+        c_kv = rng.normal(size=(1, 7, r)).astype(np.float32)
+        k_rope = rng.normal(size=(1, 7, rd)).astype(np.float32)
+        kv.ingest(1, kv.rows("a", 0, 7), {"c_kv": jnp.asarray(c_kv),
+                                          "k_rope": jnp.asarray(k_rope)})
+        for t in range(7):
+            want[0, pt[0, t // 4], t % 4] = np.concatenate(
+                [c_kv[0, t], k_rope[0, t]])
+    np.testing.assert_array_equal(np.asarray(kv.k_pools[1]), want)
+    assert not np.asarray(kv.k_pools[1])[:, 0].any()
+    assert not np.asarray(kv.k_pools[0]).any()
+
+
 def test_rejects_non_uniform_attention():
+    """SSM state layers stay off the paged path; MLA is on it, with one
+    latent pool a layer ([1, P+1, T, r+rd]) and a page cost of
+    L * T * (r+rd) * itemsize, where GQA's is 2 * L * T * KV * hd."""
     mla = dataclasses.replace(ARCHS["deepseek-v2-lite-16b"].reduced(),
                               dtype="float32")
-    with pytest.raises(ValueError):
-        PagedKVCache(mla, MemoryLedger(None))
+    kv = PagedKVCache(mla, MemoryLedger(None), page_tokens=4, max_pages=6)
+    width = mla.mla.kv_lora_rank + mla.mla.qk_rope_head_dim
+    assert [p.shape for p in kv.k_pools] == [(1, 7, 4, width)] * 2
+    assert kv.v_pools == []
+    assert kv.page_bytes == page_bytes_for(mla, 4) == 2 * 4 * width * 4
+    assert kv.device_pool_bytes == 2 * 7 * 4 * width * 4
+    full = ARCHS["deepseek-v2-lite-16b"]
+    assert page_bytes_for(full, 16) == 27 * 16 * 576 * 2
     ssm = dataclasses.replace(ARCHS["rwkv6-3b"].reduced(), dtype="float32")
     with pytest.raises(ValueError):
         PagedKVCache(ssm, MemoryLedger(None))

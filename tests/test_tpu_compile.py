@@ -61,6 +61,18 @@ def test_paged_attention_compiles(one_chip, KV):
              ((B, NP), jnp.int32), ((B,), jnp.int32))
 
 
+def test_paged_attention_compiles_for_latent_pages(one_chip):
+    """DeepSeek-V2-Lite's absorbed MLA decode: 16 query heads over one
+    latent "KV head" of width 576, the pool as both keys and values."""
+    m = get_arch("deepseek-v2-lite-16b").mla
+    W = m.kv_lora_rank + m.qk_rope_head_dim
+    B, H, T, P, NP = 8, 16, 16, 64, 8
+    _compile(lambda q, pool, pt, sl: paged_attention(q, pool, pool, pt, sl),
+             one_chip, ((B, H, W), jnp.bfloat16),
+             ((1, P + 1, T, W), jnp.bfloat16),
+             ((B, NP), jnp.int32), ((B,), jnp.int32))
+
+
 @pytest.mark.parametrize("bits,N", [(8, F), (4, F), (8, V), (4, V)],
                          ids=["int8-mlp", "int4-mlp", "int8-head",
                               "int4-head"])

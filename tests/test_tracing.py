@@ -284,3 +284,111 @@ def test_swap_history_stays_bounded(setup, monkeypatch):
     full = SwapStats()
     full.timeline.extend(("exec", float(i), float(i)) for i in range(50))
     assert [e[1] for e in full.timeline] == [float(i) for i in range(10, 50)]
+
+
+@pytest.fixture(scope="module")
+def moe_traced(tmp_path_factory):
+    """A reduced DeepSeek-V2-Lite (a dense layer, then a MoE layer of 16
+    experts in two expert-group units) serving two requests through the
+    batch engine under a profiler session."""
+    cfg = dataclasses.replace(ARCHS["deepseek-v2-lite-16b"].reduced(),
+                              dtype="float32")
+    cfg = dataclasses.replace(cfg, moe=dataclasses.replace(
+        cfg.moe, n_routed=16, top_k=4))
+    model = Model(cfg)
+    params = model.init(jax.random.key(0))
+    rng = np.random.default_rng(1)
+    trace_dir = str(tmp_path_factory.mktemp("moe_trace"))
+    with tempfile.TemporaryDirectory() as d:
+        sm, be = _engine(model, params, d, max_pages=16)
+        for i in range(2):
+            be.submit(Request(i, list(map(int, rng.integers(
+                0, cfg.vocab_size, 6))), max_new_tokens=3))
+        jax.profiler.start_trace(trace_dir)
+        be.run_all()
+        jax.profiler.stop_trace()
+        sm.close()
+    return _events(trace_dir), be, model, params
+
+
+def test_expert_group_spans_nest_in_exec(moe_traced):
+    """Each expert group's dispatch is a ``swapnet.exec.experts`` span
+    (layer, group) inside its block's ``exec``; its units stream under
+    their own names."""
+    events, be, _, _ = moe_traced
+    experts = [e for e in events if e[1] == "exec.experts"]
+    execs = [e for e in events if e[1] == "exec"]
+    passes = len([t for t in be.trace if t.batch]) + 2       # + admissions
+    assert len(experts) == 2 * passes
+    assert sorted({(e[4]["layer"], e[4]["group"]) for e in experts}) == [
+        (1, 0), (1, 1)]
+    for ev in experts:
+        assert len([x for x in execs if _inside(ev, x)]) == 1
+    units = {e[4]["unit"] for e in events if e[1] == "swap.dispatch"}
+    assert {"layer001_moe", "layer001_experts0",
+            "layer001_experts1"} <= units
+
+
+def test_expert_counters_add_no_host_sync(moe_traced, monkeypatch):
+    """The expert counters stay on the device through a step. Every host
+    read of a device value the step could make (``int``, ``float``,
+    ``tolist``, ``device_get`` of an array, and any read of the routed
+    count) is counted: a decode step makes none, and ``stats()`` reads the
+    routed count once. (On the CPU ``np.asarray`` of an array is a view
+    with no transfer; the engine's read of the logits is one.)"""
+    from jax._src import array as jax_array
+    from repro.core import runtime
+    _, _, model, params = moe_traced
+    reads = []
+    value = jax_array.ArrayImpl._value
+
+    class Watched:
+        """The routed count as the executor holds it between programs."""
+
+        def __init__(self, a):
+            self.a = a
+
+        def __float__(self):
+            reads.append("routed")
+            return float(self.a)
+
+        __int__ = __float__
+
+        def __array__(self, *args, **kw):
+            reads.append("routed")
+            return np.asarray(self.a, *args, **kw)
+
+    program = runtime.expert_group
+
+    def expert_group(w, carry, lo, routed, last):
+        if isinstance(routed, Watched):
+            routed = routed.a
+        out, routed = program(w, carry, lo, routed, last)
+        return out, Watched(routed)
+
+    def counted(self):
+        reads.append(self.shape)
+        return value.fget(self)
+    with tempfile.TemporaryDirectory() as d:
+        sm, be = _engine(model, params, d, max_pages=16)
+        for i in range(2):
+            be.submit(Request(i, [3, 1, 4, 1, 5, 9], max_new_tokens=4))
+        monkeypatch.setattr(runtime, "expert_group", expert_group)
+        be.step()                               # admissions, first step
+        step = sm.decode_step_paged
+
+        def decode_step_paged(batch, view):
+            n = len(reads)
+            out = step(batch, view)
+            assert len(reads) == n, reads[n:]   # no read inside the step
+            return out
+        monkeypatch.setattr(sm, "decode_step_paged", decode_step_paged)
+        monkeypatch.setattr(jax_array.ArrayImpl, "_value", property(counted))
+        tr = be.step()
+        assert tr.batch and reads == []
+        st = be.stats()
+        assert reads == ["routed", ()]             # one scalar, once
+        monkeypatch.undo()
+        sm.close()
+    assert st["experts_streamed"] == 16 * 4       # 2 admissions, 2 steps
+    assert 0 < st["experts_routed"] <= st["experts_streamed"]
